@@ -59,7 +59,7 @@ def run() -> list[tuple]:
 
     L, n_slots, bt, hkv, hd = 4, 8, 16, 2, 32
     k = jnp.zeros((L, n_slots * bt, hkv, hd), jnp.float32)
-    blocks = ops.kv_gather_write(k, k, jnp.arange(4, dtype=jnp.int32), bt, mode="pallas")
+    blocks = ops.kv_gather_write(k, k, jnp.arange(4, dtype=jnp.int32), bt, mode="interpret")
     rows.append(
         ("exp09.kernel_single_launch", "1",
          f"kv_gather_write packs {2*L*4} fragments in one pallas_call; "

@@ -78,7 +78,7 @@ def run() -> list[tuple]:
 
     kv = jnp.arange(64 * 2 * 32, dtype=jnp.float32).reshape(64, 2, 32)
     ids = jnp.asarray([3, 9, 11, 40, 41, 63], jnp.int32)
-    out = ops.sparse_kv_gather(kv, ids, mode="pallas")
+    out = ops.sparse_kv_gather(kv, ids, mode="interpret")
     ok = bool(jnp.array_equal(out, ref.sparse_kv_gather_ref(kv, ids)))
     rows.append(("exp10.kernel_allclose", "1", f"ok={ok}"))
     return rows

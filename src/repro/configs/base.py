@@ -240,7 +240,6 @@ def shape_applicable(model: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]
 class RuntimeConfig:
     """Knobs that do not change model math, only execution."""
 
-    kernel_mode: str = "auto"  # auto | pallas | jnp
     remat: str = "full"  # none | full | dots (checkpoint policy for train)
     attn_chunk_q: int = 512
     attn_chunk_kv: int = 1024

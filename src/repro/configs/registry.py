@@ -165,6 +165,7 @@ QWEN3_32B = ModelConfig(
     n_kv_heads=8,
     d_ff=25600,
     vocab_size=151936,
+    d_head=128,  # published head_dim (not d_model / n_heads = 80)
     source="arXiv:2505.09388 (paper's eval model)",
 )
 

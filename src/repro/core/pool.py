@@ -52,7 +52,7 @@ class PoolLayout:
 
     @property
     def fragment_bytes(self) -> int:
-        """One (layer, k|v) fragment for a block: the paper's 20 KB unit."""
+        """One (layer, k|v) fragment of a block (Qwen3-32B: 32 KiB)."""
         return self.block_tokens * self.n_kv_heads * self.head_dim * self.dtype_bytes
 
     @property

@@ -24,21 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 MeshAxes = tuple[str, ...] | str | None
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` (jax >= 0.4.38, kwarg ``check_vma``) or the
-    ``jax.experimental.shard_map`` original (kwarg ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 def _default_rules(multi_pod: bool) -> dict[str, MeshAxes]:
     fsdp: MeshAxes = ("pod", "data") if multi_pod else ("data",)
     batch: MeshAxes = ("pod", "data") if multi_pod else ("data",)

@@ -88,8 +88,10 @@ def kv_gather_write(
 # ---------------------------------------------------------------------------
 
 
-def _scatter_read_body(slot_ref, pool_ref, k_ref, v_ref):
-    # pool_ref: (1, L, 2, bt, hkv, hd); k_ref/v_ref: (L, 1, bt, hkv, hd)
+def _scatter_read_body(slot_ref, pool_ref, k0_ref, v0_ref, k_ref, v_ref):
+    # pool_ref: (1, L, 2, bt, hkv, hd); k_ref/v_ref: (L, 1, bt, hkv, hd).
+    # k0_ref/v0_ref are the zeroed outputs' aliases, left in HBM untouched.
+    del k0_ref, v0_ref
     k_ref[:, 0] = pool_ref[0, :, 0]
     v_ref[:, 0] = pool_ref[0, :, 1]
 
@@ -103,11 +105,13 @@ def kv_scatter_read(
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (k_cache, v_cache) of shape (L, n_slots*bt, hkv, hd).
 
-    Unwritten slots are zero (the engine only reads slots it mapped).
+    Unwritten slots are zero: the outputs alias zero-filled buffers, and the
+    grid writes only the mapped slots over them.
     """
     n_blocks, twoL, bt, hkv, hd = pool_blocks.shape
     L = twoL // 2
     pool = pool_blocks.reshape(n_blocks, L, 2, bt, hkv, hd)
+    zeros = jnp.zeros((L, n_slots, bt, hkv, hd), pool_blocks.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -117,6 +121,8 @@ def kv_scatter_read(
                 (1, L, 2, bt, hkv, hd),
                 lambda bi, slot_ref: (bi, 0, 0, 0, 0, 0),
             ),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec(
@@ -136,8 +142,10 @@ def kv_scatter_read(
             jax.ShapeDtypeStruct((L, n_slots, bt, hkv, hd), pool_blocks.dtype),
             jax.ShapeDtypeStruct((L, n_slots, bt, hkv, hd), pool_blocks.dtype),
         ],
+        # operand indices count the scalar-prefetch slot ids: 2, 3 = zeros
+        input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
-    )(slot_ids.astype(jnp.int32), pool)
+    )(slot_ids.astype(jnp.int32), pool, zeros, zeros)
     return (
         k.reshape(L, n_slots * bt, hkv, hd),
         v.reshape(L, n_slots * bt, hkv, hd),

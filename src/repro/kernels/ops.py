@@ -1,10 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``kernel_mode``:
-  * "pallas"  — force the Pallas path (interpret=True off-TPU, so the kernel
-                body executes in Python on CPU: correctness, not speed);
-  * "jnp"     — force the pure-jnp oracle (ref.py);
-  * "auto"    — Pallas on TPU, oracle elsewhere (the dry-run/CPU default).
+``mode`` is always named by the caller; nothing falls back in silence:
+  * "pallas"    — the compiled Pallas kernel; raises off a TPU;
+  * "interpret" — the Pallas kernel body run by the interpreter (any
+                  backend: correctness on CPU, never speed);
+  * "jnp"       — the pure-jnp oracle (ref.py).
 """
 
 from __future__ import annotations
@@ -19,19 +19,27 @@ from repro.kernels import kv_transfer as _kv
 from repro.kernels import paged_attention as _pa
 from repro.kernels import ref as _ref
 
+MODES = ("pallas", "interpret", "jnp")
+
 
 def _use_pallas(mode: str) -> tuple[bool, bool]:
     """-> (use_pallas, interpret)"""
-    on_tpu = jax.default_backend() == "tpu"
     if mode == "pallas":
-        return True, not on_tpu
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"mode='pallas' needs a TPU, backend is {jax.default_backend()!r};"
+                " ask for mode='interpret' or mode='jnp' by name"
+            )
+        return True, False
+    if mode == "interpret":
+        return True, True
     if mode == "jnp":
         return False, False
-    return on_tpu, False
+    raise ValueError(f"unknown kernel mode {mode!r}; expected one of {MODES}")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "mode", "block_q", "block_kv"))
-def flash_attention(q, k, v, *, causal=True, mode="auto", block_q=256, block_kv=512):
+def flash_attention(q, k, v, *, causal=True, mode="pallas", block_q=256, block_kv=512):
     use, interp = _use_pallas(mode)
     if use:
         return _fa.flash_attention(
@@ -42,7 +50,7 @@ def flash_attention(q, k, v, *, causal=True, mode="auto", block_q=256, block_kv=
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
-def paged_attention(q, kv_pool, block_table, context_lens, *, mode="auto"):
+def paged_attention(q, kv_pool, block_table, context_lens, *, mode="pallas"):
     use, interp = _use_pallas(mode)
     if use:
         return _pa.paged_attention(
@@ -52,7 +60,7 @@ def paged_attention(q, kv_pool, block_table, context_lens, *, mode="auto"):
 
 
 @functools.partial(jax.jit, static_argnames=("block_tokens", "mode"))
-def kv_gather_write(k_cache, v_cache, slot_ids, block_tokens, *, mode="auto"):
+def kv_gather_write(k_cache, v_cache, slot_ids, block_tokens, *, mode="pallas"):
     use, interp = _use_pallas(mode)
     if use:
         return _kv.kv_gather_write(
@@ -62,7 +70,7 @@ def kv_gather_write(k_cache, v_cache, slot_ids, block_tokens, *, mode="auto"):
 
 
 @functools.partial(jax.jit, static_argnames=("n_slots", "mode"))
-def kv_scatter_read(pool_blocks, slot_ids, n_slots, *, mode="auto"):
+def kv_scatter_read(pool_blocks, slot_ids, n_slots, *, mode="pallas"):
     use, interp = _use_pallas(mode)
     if use:
         return _kv.kv_scatter_read(pool_blocks, slot_ids, n_slots, interpret=interp)
@@ -75,7 +83,7 @@ def kv_scatter_read(pool_blocks, slot_ids, n_slots, *, mode="auto"):
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
-def sparse_kv_gather(kv, token_ids, *, mode="auto"):
+def sparse_kv_gather(kv, token_ids, *, mode="pallas"):
     use, interp = _use_pallas(mode)
     if use:
         return _kv.sparse_kv_gather(kv, token_ids, interpret=interp)
@@ -83,7 +91,7 @@ def sparse_kv_gather(kv, token_ids, *, mode="auto"):
 
 
 @functools.partial(jax.jit, static_argnames=("nh_tile", "mode"))
-def ssd_chunk(x, a_log, b_mat, c_mat, *, nh_tile=8, mode="auto"):
+def ssd_chunk(x, a_log, b_mat, c_mat, *, nh_tile=8, mode="pallas"):
     """Intra-chunk SSD + chunk states; (nb, Lc, nh, hp) tiles."""
     use, interp = _use_pallas(mode)
     if use:

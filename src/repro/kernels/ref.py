@@ -2,7 +2,7 @@
 
 Each ``*_ref`` mirrors its kernel's exact signature and is used by
 ``tests/test_kernels.py`` for allclose sweeps over shapes/dtypes, and by
-``ops.py`` as the fallback path on backends without Pallas support.
+``ops.py`` when a caller asks for ``mode="jnp"``.
 """
 
 from __future__ import annotations

@@ -162,6 +162,9 @@ def main() -> None:
     from repro.configs.base import SHAPES
     from repro.configs.registry import ASSIGNED
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     overrides = json.loads(args.runtime_json) if args.runtime_json else None
 
     cells: list[tuple[str, str, bool]] = []

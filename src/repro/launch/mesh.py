@@ -10,19 +10,13 @@ from __future__ import annotations
 
 import jax
 
-
-def axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` when this jax has AxisType (>= 0.4.38);
-    older versions are implicitly Auto."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AUTO,) * len(axes))
 
 
 def make_debug_mesh(*, multi_pod: bool = False, model: int = 4):
@@ -34,7 +28,7 @@ def make_debug_mesh(*, multi_pod: bool = False, model: int = 4):
     else:
         shape = (max(1, n // model), model)
         axes = ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AUTO,) * len(axes))
 
 
 # TPU v5e hardware constants (roofline targets; the container runs CPU-only)

@@ -42,17 +42,20 @@ def main() -> None:
     from repro.configs.registry import get_config, reduced_config
     from repro.data.pipeline import DataConfig, make_dataset
     from repro.distributed.sharding import AxisRules
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import Model
     from repro.training import optimizer as opt_lib
     from repro.training.optimizer import OptimizerConfig
     from repro.training.train_loop import TrainLoopConfig, run_train_loop
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.smoke else get_config(args.arch)
     rules = None
     if d * m > 1:
-        from repro.launch.mesh import axis_types_kw
-
-        mesh = jax.make_mesh((d, m), ("data", "model"), **axis_types_kw(2))
+        mesh = jax.make_mesh(
+            (d, m), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
         rules = AxisRules.create(mesh)
     runtime = RuntimeConfig(
         remat="full", attn_chunk_q=64, attn_chunk_kv=64, moe_dispatch="einsum"

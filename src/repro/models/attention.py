@@ -293,16 +293,9 @@ def decode_attention_interleaved(
 
     def local_fn(q, k_shard, v_shard, cache_len):
         # row-major shard id across the (possibly multiple) kv axes
-        # (lax.axis_size only exists in jax >= 0.4.38; psum(1) is the
-        # classic spelling of the same quantity)
         shard_id = 0
         for ax in axes:
-            size = (
-                jax.lax.axis_size(ax)
-                if hasattr(jax.lax, "axis_size")
-                else jax.lax.psum(1, ax)
-            )
-            shard_id = shard_id * size + jax.lax.axis_index(ax)
+            shard_id = shard_id * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         b_loc, s_loc = k_shard.shape[0], k_shard.shape[1]
         pos = shard_id * s_loc + jnp.arange(s_loc)
         local_mask = pos[None, :] < jnp.reshape(cache_len, (-1, 1))
@@ -322,10 +315,9 @@ def decode_attention_interleaved(
         P(b_ax),  # cache_len
     )
     out_specs = P(b_ax, None, None, None)
-    from repro.distributed.sharding import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, cache_len)
 

@@ -268,10 +268,9 @@ def _a2a_dispatch(p, x, cfg, rules):
         P("model", None, None),  # wo
     )
     out_specs = (P(batch_ax, "model", None), P())
-    from repro.distributed.sharding import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
     return fn(x, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
 

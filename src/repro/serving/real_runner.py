@@ -1,7 +1,8 @@
 """RealEngine: actual token generation through the Beluga KVCache stack.
 
-CPU-runnable end-to-end driver (reduced configs): prompts are served with
-real numerics and REAL pool reuse —
+End-to-end driver, on a TPU at published widths (``layers=`` cuts depth
+only) or on the CPU at reduced widths: prompts are served with real
+numerics and REAL pool reuse —
 
   miss: prefill -> per-layer KV packed into pool blocks (kv_gather_write
         kernel) -> blocks published in the GlobalIndex;
@@ -10,13 +11,14 @@ real numerics and REAL pool reuse —
         (not covering a full block) are stepped through decode.
 
 Restricted to homogeneous attention stacks (period-1 archs: olmo, qwen,
-command-r, internlm2, musicgen, internvl2 backbones) — hybrid/ssm archs
-pool their recurrent state snapshots instead (see DESIGN.md §5) and are
-exercised via the simulated cluster.
+command-r, internlm2, musicgen, internvl2 backbones). Hybrid/ssm archs
+would need their recurrent state pooled beside the KV blocks, which the
+pool does not do yet; they run only in the simulated cluster.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from dataclasses import dataclass
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import RuntimeConfig
-from repro.configs.registry import reduced_config
+from repro.configs.registry import get_config, reduced_config
 from repro.core.index import GlobalIndex
 from repro.core.pool import BelugaPool, PoolLayout
 from repro.kernels import ops
@@ -41,7 +43,7 @@ class RealEngine:
     index: GlobalIndex
     params: dict
     max_len: int
-    kernel_mode: str = "auto"
+    kernel_mode: str = "pallas"
 
     @classmethod
     def create(
@@ -50,17 +52,20 @@ class RealEngine:
         max_len: int = 128,
         pool_blocks: int = 256,
         seed: int = 0,
-        kernel_mode: str = "auto",
+        kernel_mode: str = "pallas",
+        layers: int | None = None,
     ) -> "RealEngine":
-        cfg = reduced_config(arch)
+        """``layers=None``: the reduced CPU config; ``layers=n``: the
+        published widths with the depth cut to ``n`` layers."""
+        if layers is None:
+            cfg = reduced_config(arch)
+        else:
+            cfg = dataclasses.replace(get_config(arch), n_layers=layers)
         assert stack_lib.period_length(cfg) == 1 and cfg.n_heads > 0, (
             "RealEngine needs a homogeneous attention stack"
         )
-        runtime = RuntimeConfig(
-            remat="none", attn_chunk_q=32, attn_chunk_kv=32, decode_kv="replicated"
-        )
-        model = Model(cfg, runtime)
-        params = model.init(jax.random.key(seed))
+        model = Model(cfg, RuntimeConfig(remat="none", decode_kv="replicated"))
+        params = jax.jit(model.init)(jax.random.key(seed))
         layout = PoolLayout(
             block_tokens=16,
             n_layers_kv=cfg.n_layers,
@@ -122,35 +127,39 @@ class RealEngine:
             logits = None
             for t in range(start, len(prompt)):
                 logits, cache = self._decode(
-                    cache, jnp.asarray([prompt[t]]), jnp.asarray([t])
+                    self.params, cache, jnp.asarray([prompt[t]]), jnp.asarray([t])
                 )
         else:
             # --- prefill path + pool writeback ---
             batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
-            logits, cache = self._prefill(batch)
+            logits, cache = self._prefill(self.params, batch)
             self._writeback(prompt, cache)
 
+        tok, ok = _greedy(logits)
+        out, finite = [int(tok)], [ok]  # int() waits for the device
         info["ttft_s"] = time.time() - t_start
-        out = [int(jnp.argmax(logits[0]))]
         pos = len(prompt)
         while len(out) < max_new and pos + 1 < self.max_len:
             logits, cache = self._decode(
-                cache, jnp.asarray([out[-1]]), jnp.asarray([pos])
+                self.params, cache, jnp.asarray([out[-1]]), jnp.asarray([pos])
             )
-            out.append(int(jnp.argmax(logits[0])))
+            tok, ok = _greedy(logits)
+            out.append(int(tok))
+            finite.append(ok)
             pos += 1
         info["total_s"] = time.time() - t_start
+        info["logits_finite"] = bool(jnp.stack(finite).all())
         return out, info
 
     # ------------------------------------------------------------------
+    # params are arguments of the compiled programs, never baked-in constants
     @functools.cached_property
     def _prefill(self):
-        return jax.jit(functools.partial(self.model.prefill_fn, self.params,
-                                         max_len=self.max_len))
+        return jax.jit(functools.partial(self.model.prefill_fn, max_len=self.max_len))
 
     @functools.cached_property
     def _decode(self):
-        return jax.jit(functools.partial(self.model.decode_fn, self.params))
+        return jax.jit(self.model.decode_fn)
 
     def _writeback(self, prompt: list[int], cache: dict) -> None:
         bt = self.pool.layout.block_tokens
@@ -170,3 +179,9 @@ class RealEngine:
         # one batched publish (single lock, one scatter per column)
         epochs = self.pool.write_blocks(block_ids)
         self.index.publish_many(list(keys[: len(block_ids)]), block_ids, epochs, bt)
+
+
+@jax.jit
+def _greedy(logits: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(1, V) logits -> (greedy next token, whether every logit is finite)."""
+    return jnp.argmax(logits[0]), jnp.isfinite(logits).all()

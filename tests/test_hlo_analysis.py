@@ -18,8 +18,7 @@ def test_analyzer_counts_scan_trip_counts():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_analysis import analyze_hlo
 
-        from repro.launch.mesh import axis_types_kw
-        mesh = jax.make_mesh((2, 4), ("data", "model"), **axis_types_kw(2))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         def body(x, w):
             def layer(h, wl):
                 h = jnp.tanh(h @ wl)

@@ -36,7 +36,7 @@ def test_flash_attention_matches_oracle(shape, dtype):
     q = _randn((b, sq, hq, d), dtype)
     k = _randn((b, skv, hkv, d), dtype)
     v = _randn((b, skv, hkv, d), dtype)
-    out = ops.flash_attention(q, k, v, causal=True, mode="pallas",
+    out = ops.flash_attention(q, k, v, causal=True, mode="interpret",
                               block_q=32, block_kv=32)
     want = ref.flash_attention_ref(q, k, v, causal=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -50,7 +50,7 @@ def test_flash_attention_noncausal():
     q = _randn((1, 64, 4, 64), jnp.float32)
     k = _randn((1, 64, 4, 64), jnp.float32)
     v = _randn((1, 64, 4, 64), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=False, mode="pallas",
+    out = ops.flash_attention(q, k, v, causal=False, mode="interpret",
                               block_q=32, block_kv=32)
     want = ref.flash_attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
@@ -79,7 +79,7 @@ def test_paged_attention_matches_oracle(shape, dtype):
         jnp.int32,
     )
     ctx = jnp.asarray(RNG.integers(1, mb * bt, size=(b,)), jnp.int32)
-    out = ops.paged_attention(q, pool, tbl, ctx, mode="pallas")
+    out = ops.paged_attention(q, pool, tbl, ctx, mode="interpret")
     want = ref.paged_attention_ref(q, pool, tbl, ctx)
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(
@@ -99,19 +99,40 @@ def test_kv_transfer_roundtrip(dtype, L, n_slots, bt, hkv, hd):
     k = _randn((L, n_slots * bt, hkv, hd), dtype)
     v = _randn((L, n_slots * bt, hkv, hd), dtype)
     slots = jnp.asarray(RNG.choice(n_slots, size=3, replace=False), jnp.int32)
-    blocks_p = ops.kv_gather_write(k, v, slots, bt, mode="pallas")
+    blocks_p = ops.kv_gather_write(k, v, slots, bt, mode="interpret")
     blocks_r = ref.kv_gather_write_ref(k, v, slots, bt)
     assert jnp.array_equal(blocks_p, blocks_r)
-    k2, v2 = ops.kv_scatter_read(blocks_p, slots, n_slots, mode="pallas")
+    k2, v2 = ops.kv_scatter_read(blocks_p, slots, n_slots, mode="interpret")
     for s in np.asarray(slots):
         assert jnp.array_equal(k2[:, s * bt : (s + 1) * bt], k[:, s * bt : (s + 1) * bt])
         assert jnp.array_equal(v2[:, s * bt : (s + 1) * bt], v[:, s * bt : (s + 1) * bt])
+    # every slot is defined: the unwritten ones are zero, as in the oracle
+    k3, v3 = ops.kv_scatter_read(blocks_p, slots, n_slots, mode="jnp")
+    assert jnp.array_equal(k2, k3) and jnp.array_equal(v2, v3)
+
+
+@pytest.mark.parametrize("kernel", ["kv_gather_write", "kv_scatter_read"])
+def test_pallas_mode_raises_off_tpu(kernel):
+    """No silent fallback: off a TPU, compiled Pallas is refused, never
+    swapped for the interpreter or the oracle."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("on a TPU, mode='pallas' is the compiled kernel")
+    k = jnp.zeros((1, 32, 1, 16), jnp.float32)
+    slots = jnp.arange(2, dtype=jnp.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        if kernel == "kv_gather_write":
+            ops.kv_gather_write(k, k, slots, 16, mode="pallas")
+        else:
+            blocks = ops.kv_gather_write(k, k, slots, 16, mode="jnp")
+            ops.kv_scatter_read(blocks, slots, 2, mode="pallas")
+    with pytest.raises(ValueError, match="unknown kernel mode"):
+        ops.kv_gather_write(k, k, slots, 16, mode="auto")
 
 
 def test_sparse_gather_matches_oracle():
     kv = _randn((64, 2, 32), jnp.float32)
     ids = jnp.asarray(RNG.choice(64, size=17, replace=False), jnp.int32)
-    out = ops.sparse_kv_gather(kv, ids, mode="pallas")
+    out = ops.sparse_kv_gather(kv, ids, mode="interpret")
     assert jnp.array_equal(out, ref.sparse_kv_gather_ref(kv, ids))
 
 
@@ -131,7 +152,7 @@ def test_sparse_gather_property(n_sel, n_tokens):
     kv = jnp.arange(n_tokens * 2 * 8, dtype=jnp.float32).reshape(n_tokens, 2, 8)
     rng = np.random.default_rng(n_sel * 977 + n_tokens)
     ids = jnp.asarray(rng.integers(0, n_tokens, size=n_sel), jnp.int32)
-    out = ops.sparse_kv_gather(kv, ids, mode="pallas")
+    out = ops.sparse_kv_gather(kv, ids, mode="interpret")
     assert out.shape == (n_sel, 2, 8)
     for i, t in enumerate(np.asarray(ids)):
         assert jnp.array_equal(out[i], kv[t])
@@ -170,7 +191,7 @@ def test_ssd_chunk_matches_oracle(nb, lc, nh, hp, n, tile, dtype):
     a = jnp.asarray(-np.abs(RNG.normal(size=(nb, lc, nh))) * 0.1, jnp.float32)
     b = _randn((nb, lc, nh, n), dtype)
     c = _randn((nb, lc, nh, n), dtype)
-    yp, sp = ops.ssd_chunk(x, a, b, c, nh_tile=tile, mode="pallas")
+    yp, sp = ops.ssd_chunk(x, a, b, c, nh_tile=tile, mode="interpret")
     yr, sr = ops.ssd_chunk(x, a, b, c, mode="jnp")
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-4
     np.testing.assert_allclose(np.asarray(yp), np.asarray(yr), atol=tol, rtol=tol)
@@ -191,7 +212,7 @@ def test_ssd_chunk_matches_model_path():
     y_model, state_model = _ssd_chunked(x, a, bm, cm, chunk=s)  # one chunk
     bh = jnp.broadcast_to(bm, (b, s, nh, n))
     ch = jnp.broadcast_to(cm, (b, s, nh, n))
-    yk, sk = ops.ssd_chunk(x, a, bh, ch, nh_tile=4, mode="pallas")
+    yk, sk = ops.ssd_chunk(x, a, bh, ch, nh_tile=4, mode="interpret")
     np.testing.assert_allclose(np.asarray(y_model[:, :s].reshape(b, s, nh, hp)),
                                np.asarray(yk), atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(state_model), np.asarray(sk[0][None]),

@@ -294,7 +294,9 @@ def test_cluster_liveness_property(n, in_len, policy):
 def test_real_engine_pool_reuse_is_exact():
     from repro.serving.real_runner import RealEngine
 
-    eng = RealEngine.create("olmo-1b", max_len=96, pool_blocks=64)
+    eng = RealEngine.create(
+        "olmo-1b", max_len=96, pool_blocks=64, kernel_mode="interpret"
+    )
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, eng.cfg.vocab_size, size=48).tolist()
     out1, info1 = eng.generate(prompt, max_new=8)
@@ -302,3 +304,28 @@ def test_real_engine_pool_reuse_is_exact():
     out2, info2 = eng.generate(prompt, max_new=8)
     assert info2["hit_tokens"] == 48  # full-prefix pool hit
     assert out1 == out2  # pool roundtrip preserves numerics exactly
+
+
+def test_real_engine_params_are_program_arguments():
+    """The weights reach the compiled prefill and decode as arguments of
+    ``main``, not as constants baked into the program."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.serving.real_runner import RealEngine
+
+    eng = RealEngine.create("olmo-1b", max_len=64, pool_blocks=64, kernel_mode="jnp")
+    n_params = len(jax.tree.leaves(eng.params))
+    batch = {"tokens": jnp.zeros((1, 32), jnp.int32)}
+
+    def n_main_args(lowered) -> int:
+        sig = re.search(r"func\.func public @main\((.*?)\) ->", lowered.as_text())
+        return len(re.findall(r"%arg\d+:", sig.group(1)))
+
+    assert n_main_args(eng._prefill.lower(eng.params, batch)) == n_params + 1
+    _, cache = eng._prefill(eng.params, batch)
+    tok = jnp.zeros((1,), jnp.int32)
+    low = eng._decode.lower(eng.params, cache, tok, tok)
+    assert n_main_args(low) == n_params + len(jax.tree.leaves(cache)) + 2

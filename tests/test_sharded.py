@@ -30,8 +30,7 @@ from repro.configs.registry import reduced_config
 from repro.configs.base import RuntimeConfig
 from repro.models import Model
 from repro.distributed.sharding import AxisRules
-from repro.launch.mesh import axis_types_kw
-mesh = jax.make_mesh((2, 4), ("data", "model"), **axis_types_kw(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = AxisRules.create(mesh)
 """
 

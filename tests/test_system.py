@@ -33,7 +33,9 @@ def _reqs(n, in_len=1024, out_len=8, tag="r", arrival=0.0, distinct=False):
 def test_c1_pool_reuse_exactness():
     from repro.serving.real_runner import RealEngine
 
-    eng = RealEngine.create("qwen1.5-0.5b", max_len=96, pool_blocks=64)
+    eng = RealEngine.create(
+        "qwen1.5-0.5b", max_len=96, pool_blocks=64, kernel_mode="interpret"
+    )
     rng = np.random.default_rng(7)
     p1 = rng.integers(0, eng.cfg.vocab_size, size=48).tolist()
     out_cold, info_cold = eng.generate(p1, max_new=6)

@@ -1,0 +1,89 @@
+"""Compile-only rehearsals for a described TPU v5e chip (no chip attached).
+
+The TPU compiler refuses here what it would refuse on the chip: tiling a
+kernel cannot use, more fast memory than a kernel may hold, a program that
+does not fit in HBM. Shapes are qwen3-32b's published KV and model widths.
+
+The topology is described inside the module fixture, never at import: only
+one process may load the TPU library, and every pytest worker imports this
+file. Keep every such rehearsal in this one file.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.configs.registry import get_config
+
+HBM_BYTES = 16 * 10**9  # TPU v5e, 16 GB
+QWEN3 = get_config("qwen3-32b")
+BT, N_SLOTS, N_BLOCKS = 16, 64, 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to rehearse
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("n_layers", [4, 64])
+@pytest.mark.parametrize("kernel", ["kv_gather_write", "kv_scatter_read"])
+def test_kv_transfer_kernel_compiles_for_v5e(one_chip, kernel, n_layers):
+    from repro.kernels import kv_transfer
+
+    hkv, hd = QWEN3.n_kv_heads, QWEN3.head_dim
+    ids = _sds((N_BLOCKS,), jnp.int32, one_chip)
+    if kernel == "kv_gather_write":
+        kc = _sds((n_layers, N_SLOTS * BT, hkv, hd), jnp.bfloat16, one_chip)
+        lowered = jax.jit(kv_transfer.kv_gather_write, static_argnums=3).lower(
+            kc, kc, ids, BT
+        )
+    else:
+        blocks = _sds((N_BLOCKS, 2 * n_layers, BT, hkv, hd), jnp.bfloat16, one_chip)
+        lowered = jax.jit(kv_transfer.kv_scatter_read, static_argnums=2).lower(
+            blocks, ids, N_SLOTS
+        )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
+def test_published_width_decode_step_compiles_for_v5e(one_chip):
+    """One decode step of qwen3-32b at published widths, one layer deep,
+    with the weights as arguments (shapes from ``jax.eval_shape``)."""
+    from repro.configs.base import RuntimeConfig
+    from repro.models import Model
+
+    model = Model(dataclasses.replace(QWEN3, n_layers=1),
+                  RuntimeConfig(remat="none", decode_kv="replicated"))
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda x: _sds(x.shape, x.dtype, one_chip), t
+    )
+    params = on_chip(jax.eval_shape(model.init, jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 1024)))
+    tok = _sds((1,), jnp.int32, one_chip)
+    compiled = jax.jit(model.decode_fn).lower(params, cache, tok, tok).compile()
+    n_weight_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert compiled.memory_analysis().argument_size_in_bytes >= n_weight_bytes
+    assert _device_bytes(compiled) < HBM_BYTES
