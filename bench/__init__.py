@@ -1,0 +1,1 @@
+"""On-chip benchmark of the served path (``python3 bench/run.py --help``)."""
