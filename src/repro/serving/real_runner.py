@@ -14,6 +14,24 @@ Restricted to homogeneous attention stacks (period-1 archs: olmo, qwen,
 command-r, internlm2, musicgen, internvl2 backbones). Hybrid/ssm archs
 would need their recurrent state pooled beside the KV blocks, which the
 pool does not do yet; they run only in the simulated cluster.
+
+``generate`` writes a span (``jax.profiler.TraceAnnotation``) at each
+layer boundary, named in ``SPANS``: on the profiler's host plane, on the
+device trace's clock, when a profiler is collecting, and about a
+microsecond each when none is. ``engine.generate`` carries the request id
+(``req``); the others nest inside it by time on the calling thread:
+
+  engine.generate
+    engine.lookup                  index.match_prefix
+    engine.fetch, engine.tail      hit: pool gather + kv_scatter_read, tail decode
+    engine.prefill                 miss: prefill_fn
+    engine.writeback               miss: kv_gather_write + pool write, with
+      engine.allocate              pool.allocate
+      engine.publish               keys_for + write_blocks + publish_many
+    engine.first_token             greedy pick, host waits for the token
+    engine.decode                  output tokens, each
+      engine.step                  inputs, decode_fn and greedy dispatched
+      engine.sync                  host waits for the token
 """
 
 from __future__ import annotations
@@ -25,6 +43,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import RuntimeConfig
 from repro.configs.registry import get_config, reduced_config
@@ -33,6 +52,12 @@ from repro.core.pool import BelugaPool, PoolLayout
 from repro.kernels import ops
 from repro.models import Model
 from repro.models import transformer as stack_lib
+
+SPANS = (
+    "engine.generate", "engine.lookup", "engine.fetch", "engine.tail",
+    "engine.prefill", "engine.writeback", "engine.allocate", "engine.publish",
+    "engine.first_token", "engine.decode", "engine.step", "engine.sync",
+)
 
 
 @dataclass
@@ -44,6 +69,7 @@ class RealEngine:
     params: dict
     max_len: int
     kernel_mode: str = "pallas"
+    n_requests: int = dataclasses.field(default=0, init=False)  # the next ``req`` span id
 
     @classmethod
     def create(
@@ -95,67 +121,86 @@ class RealEngine:
 
     # ------------------------------------------------------------------
     def generate(self, prompt: list[int], max_new: int = 16) -> tuple[list[int], dict]:
-        t_start = time.time()
-        bt = self.pool.layout.block_tokens
-        hits = self.index.match_prefix(prompt)
-        n_hit = len(hits) * bt
-        info = {"hit_tokens": n_hit}
+        req = self.n_requests
+        self.n_requests += 1
+        with TraceAnnotation("engine.generate", req=req):
+            t_start = time.time()
+            bt = self.pool.layout.block_tokens
+            with TraceAnnotation("engine.lookup"):
+                hits = self.index.match_prefix(prompt)
+            n_hit = len(hits) * bt
+            info = {"hit_tokens": n_hit}
 
-        if n_hit:
-            # --- pool fetch path: scatter-read hit blocks, skip prefill ---
-            block_ids = [b for _, b, _ in hits]
-            blocks = self.pool.data[jnp.asarray(block_ids)]
-            n_slots = self.max_len // bt
-            k_cache, v_cache = ops.kv_scatter_read(
-                blocks, jnp.arange(len(block_ids), dtype=jnp.int32), n_slots,
-                mode=self.kernel_mode,
-            )
-            cache = self._layers_to_cache(
-                k_cache.astype(jnp.dtype(self.cfg.dtype)),
-                v_cache.astype(jnp.dtype(self.cfg.dtype)),
-            )
-            # pad cache seq dim up to max_len if needed
-            pad = self.max_len - cache["pos_0"]["k"].shape[2]
-            if pad > 0:
-                cache = jax.tree.map(
-                    lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))),
-                    cache,
-                )
-            # step the tail through decode; if the prompt is fully covered,
-            # re-feed the last token (overwrites identical KV, yields logits)
-            start = min(n_hit, len(prompt) - 1)
-            logits = None
-            for t in range(start, len(prompt)):
-                logits, cache = self._decode(
-                    self.params, cache, jnp.asarray([prompt[t]]), jnp.asarray([t])
-                )
-        else:
-            # --- prefill path + pool writeback ---
-            batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
-            logits, cache = self._prefill(self.params, batch)
-            self._writeback(prompt, cache)
+            if n_hit:
+                # --- pool fetch path: scatter-read hit blocks, skip prefill ---
+                with TraceAnnotation("engine.fetch"):
+                    cache = self._fetch([b for _, b, _ in hits])
+                # step the tail through decode; if the prompt is fully covered,
+                # re-feed the last token (overwrites identical KV, yields logits)
+                with TraceAnnotation("engine.tail"):
+                    for t in range(min(n_hit, len(prompt) - 1), len(prompt)):
+                        logits, cache = self._decode(
+                            self.params, cache, jnp.asarray([prompt[t]]), jnp.asarray([t])
+                        )
+            else:
+                # --- prefill path + pool writeback ---
+                with TraceAnnotation("engine.prefill"):
+                    batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
+                    logits, cache = self._prefill(self.params, batch)
+                with TraceAnnotation("engine.writeback"):
+                    self._writeback(prompt, cache)
 
-        tok, ok = _greedy(logits)
-        out, finite = [int(tok)], [ok]  # int() waits for the device
-        info["ttft_s"] = time.time() - t_start
-        pos = len(prompt)
-        while len(out) < max_new and pos + 1 < self.max_len:
-            logits, cache = self._decode(
-                self.params, cache, jnp.asarray([out[-1]]), jnp.asarray([pos])
-            )
-            tok, ok = _greedy(logits)
-            out.append(int(tok))
-            finite.append(ok)
-            pos += 1
-        info["total_s"] = time.time() - t_start
-        info["logits_finite"] = bool(jnp.stack(finite).all())
+            with TraceAnnotation("engine.first_token"):
+                tok, ok = _greedy(logits, True)
+                out = [int(tok)]  # int() waits for the device
+            info["ttft_s"] = time.time() - t_start
+            pos = len(prompt)
+            with TraceAnnotation("engine.decode"):
+                while len(out) < max_new and pos + 1 < self.max_len:
+                    with TraceAnnotation("engine.step"):
+                        logits, cache = self._decode(
+                            self.params, cache, jnp.asarray([out[-1]]), jnp.asarray([pos])
+                        )
+                        tok, ok = _greedy(logits, ok)
+                    with TraceAnnotation("engine.sync"):
+                        out.append(int(tok))
+                    pos += 1
+            info["total_s"] = time.time() - t_start
+            info["logits_finite"] = bool(ok)
         return out, info
+
+    def _fetch(self, block_ids: list[int]) -> dict:
+        """A decode cache of ``max_len`` slots holding the pool blocks
+        ``block_ids`` in order."""
+        bt = self.pool.layout.block_tokens
+        blocks = pool_gather(self.pool.data, jnp.asarray(block_ids))
+        k_cache, v_cache = ops.kv_scatter_read(
+            blocks, jnp.arange(len(block_ids), dtype=jnp.int32), self.max_len // bt,
+            mode=self.kernel_mode,
+        )
+        cache = self._layers_to_cache(
+            k_cache.astype(jnp.dtype(self.cfg.dtype)),
+            v_cache.astype(jnp.dtype(self.cfg.dtype)),
+        )
+        # pad cache seq dim up to max_len if needed
+        pad = self.max_len - cache["pos_0"]["k"].shape[2]
+        if pad > 0:
+            cache = jax.tree.map(
+                lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))),
+                cache,
+            )
+        return cache
 
     # ------------------------------------------------------------------
     # params are arguments of the compiled programs, never baked-in constants
     @functools.cached_property
     def _prefill(self):
-        return jax.jit(functools.partial(self.model.prefill_fn, max_len=self.max_len))
+        model, max_len = self.model, self.max_len
+
+        def prefill_fn(params: dict, batch: dict) -> tuple[jax.Array, dict]:
+            return model.prefill_fn(params, batch, max_len=max_len)
+
+        return jax.jit(prefill_fn)
 
     @functools.cached_property
     def _decode(self):
@@ -170,18 +215,34 @@ class RealEngine:
         blocks = ops.kv_gather_write(
             k, v, jnp.arange(n_blocks, dtype=jnp.int32), bt, mode=self.kernel_mode
         )
-        block_ids = self.pool.allocate(n_blocks)
-        self.pool.data = self.pool.data.at[jnp.asarray(block_ids)].set(
-            blocks.astype(self.pool.data.dtype)
+        with TraceAnnotation("engine.allocate"):
+            block_ids = self.pool.allocate(n_blocks)
+        self.pool.data = pool_write(
+            self.pool.data, jnp.asarray(block_ids), blocks.astype(self.pool.data.dtype)
         )
-        keys = self.index.keys_for(prompt)
-        # commit AFTER the payload write (§5.1): one batched epoch bump,
-        # one batched publish (single lock, one scatter per column)
-        epochs = self.pool.write_blocks(block_ids)
-        self.index.publish_many(list(keys[: len(block_ids)]), block_ids, epochs, bt)
+        with TraceAnnotation("engine.publish"):
+            keys = self.index.keys_for(prompt)
+            # commit AFTER the payload write (§5.1): one batched epoch bump,
+            # one batched publish (single lock, one scatter per column)
+            epochs = self.pool.write_blocks(block_ids)
+            self.index.publish_many(list(keys[: len(block_ids)]), block_ids, epochs, bt)
 
 
 @jax.jit
-def _greedy(logits: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(1, V) logits -> (greedy next token, whether every logit is finite)."""
-    return jnp.argmax(logits[0]), jnp.isfinite(logits).all()
+def pool_gather(data: jax.Array, ids: jax.Array) -> jax.Array:
+    """The device pool's blocks ``ids``, copied out in order."""
+    return data[ids]
+
+
+@jax.jit
+def pool_write(data: jax.Array, ids: jax.Array, blocks: jax.Array) -> jax.Array:
+    """The device pool with ``blocks`` written at ``ids``: a new array, the
+    whole pool copied (``data`` is not donated)."""
+    return data.at[ids].set(blocks)
+
+
+@jax.jit
+def _greedy(logits: jax.Array, ok: jax.Array | bool) -> tuple[jax.Array, jax.Array]:
+    """(1, V) logits and whether every earlier logit was finite -> (greedy
+    next token, whether every logit so far is finite)."""
+    return jnp.argmax(logits[0]), ok & jnp.isfinite(logits).all()
