@@ -622,6 +622,7 @@ def handle_request(
         index.restore_entries(keys, ids.tolist(), eps.tolist(), ntk.tolist())
         return _U32.pack(n)
     if op == OP_SEED_STATS:
+        _need(buf, _HDR.size + _SEED_STATS.size)
         hits, misses = _SEED_STATS.unpack_from(buf, _HDR.size)
         index.seed_stats(hits, misses)
         return _U32.pack(0)

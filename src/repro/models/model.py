@@ -1,9 +1,9 @@
-"""Public model API: init / loss / prefill / decode / input_specs.
+"""Public model API: init / loss / prefill / decode / extend / input_specs.
 
 ``Model`` binds a ModelConfig + RuntimeConfig + (optional) mesh AxisRules and
 exposes pure functions suitable for jit/lower: ``loss_fn``, ``prefill_fn``,
-``decode_fn``. Inputs are produced by ``input_specs`` (ShapeDtypeStructs —
-the same objects the multi-pod dry-run lowers against).
+``decode_fn``, ``extend_fn``. Inputs are produced by ``input_specs``
+(ShapeDtypeStructs — the same objects the multi-pod dry-run lowers against).
 """
 
 from __future__ import annotations
@@ -154,6 +154,32 @@ class Model:
             kv_shard_axes=kv_shard_axes,
             kv_batch_axes=kv_batch_axes,
         )
+        h = norm_apply(params["final_ln"], h, cfg)
+        logits = unembed_apply(params["embed"], h, self.rules)  # (b, 1, V)
+        return logits[:, 0], new_cache
+
+    # ------------------------------------------------------------------
+    # Extend: prefill a chunk of tokens against a filled cache
+    # ------------------------------------------------------------------
+    def extend_fn(
+        self,
+        params: dict,
+        cache: dict,
+        tokens: jax.Array,  # (b, c) int32 chunk; rows from n_valid on are padding
+        start: jax.Array,  # scalar int32: position of tokens[:, 0]
+        n_valid: jax.Array,  # scalar int32: real tokens in the chunk
+    ) -> tuple[jax.Array, dict]:
+        """Prefill of the chunk at positions ``start … start+c-1`` against a
+        ``max_len`` cache that holds the positions before ``start``, as the
+        uncached tail of a prefix hit. The padding rows' KV is written but
+        no real token attends to it. Returns the (b, V) logits of row
+        ``n_valid - 1`` and the updated cache."""
+        cfg = self.cfg
+        x = embed_apply(params["embed"], tokens, self.rules)
+        h, new_cache = stack_lib.extend_stack(
+            params, cache, x, start, n_valid, cfg, self.runtime, self.rules
+        )
+        h = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
         h = norm_apply(params["final_ln"], h, cfg)
         logits = unembed_apply(params["embed"], h, self.rules)  # (b, 1, V)
         return logits[:, 0], new_cache

@@ -304,3 +304,54 @@ def decode_step_stack(
 
     h, new_cache = jax.lax.scan(period_fn, x, (params["stack"], cache))
     return h, new_cache
+
+
+def extend_stack(
+    params: dict,
+    cache: dict,
+    x: jax.Array,  # (b, c, d) embedded chunk
+    start: jax.Array,  # scalar: position of x[:, 0]
+    n_valid: jax.Array,  # scalar: rows of x that are real tokens
+    cfg: ModelConfig,
+    runtime: RuntimeConfig,
+    rules: AxisRules | None,
+):
+    """A chunk of tokens at positions ``start … start+c-1`` through an
+    attention stack whose cache already holds the positions before
+    ``start``: each layer writes the chunk's K/V into its cache at ``start``,
+    then attends causally over the cache, masked beyond ``start + n_valid``.
+    Returns (hidden, new_cache)."""
+    kinds = layer_kinds(cfg)
+    assert all(kind.mixer == "attn" for kind in kinds), "extend needs an attention stack"
+    b, c = x.shape[0], x.shape[1]
+    positions = jnp.broadcast_to(start + jnp.arange(c, dtype=jnp.int32), (b, c))
+
+    def period_fn(carry, xs):
+        h = carry
+        pp, pc = xs
+        new_caches = {}
+        for p_i, kind in enumerate(kinds):
+            layer_p = pp[f"pos_{p_i}"]
+            layer_c = pc[f"pos_{p_i}"]
+            hn = norm_apply(layer_p["ln1"], h, cfg)
+            q, k_new, v_new = attn_lib.qkv_proj(layer_p["attn"], hn, cfg, positions, rules)
+            kc = jax.lax.dynamic_update_slice_in_dim(
+                layer_c["k"], k_new.astype(layer_c["k"].dtype), start, axis=1
+            )
+            vc = jax.lax.dynamic_update_slice_in_dim(
+                layer_c["v"], v_new.astype(layer_c["v"].dtype), start, axis=1
+            )
+            o = attn_lib.flash_attention(
+                q, attn_lib._dequant(kc), attn_lib._dequant(vc), causal=True,
+                q_offset=start, kv_len=start + n_valid,
+                chunk_q=runtime.attn_chunk_q, chunk_kv=runtime.attn_chunk_kv,
+            )
+            h = h + attn_lib.out_proj(layer_p["attn"], o, rules)
+            h, _ = _ffn(layer_p, kind, h, cfg, runtime, rules)
+            if rules is not None:
+                h = constrain(h, rules, ("batch", "seq", "act_embed"))
+            new_caches[f"pos_{p_i}"] = {"k": kc, "v": vc}
+        return h, new_caches
+
+    h, new_cache = jax.lax.scan(period_fn, x, (params["stack"], cache))
+    return h, new_cache

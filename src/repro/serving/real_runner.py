@@ -7,8 +7,9 @@ numerics and REAL pool reuse —
   miss: prefill -> per-layer KV packed into pool blocks (kv_gather_write
         kernel) -> blocks published in the GlobalIndex;
   hit : pool blocks fetched (kv_scatter_read kernel) straight into a decode
-        cache — prefill for the hit prefix is SKIPPED; only the tail tokens
-        (not covering a full block) are stepped through decode.
+        cache — prefill for the hit prefix is SKIPPED; the uncached tail is
+        prefilled against the fetched cache by ``extend_fn``, in chunks of
+        ``TAIL_CHUNK`` tokens (one compiled shape for any tail).
 
 Restricted to homogeneous attention stacks (period-1 archs: olmo, qwen,
 command-r, internlm2, musicgen, internvl2 backbones). Hybrid/ssm archs
@@ -23,7 +24,9 @@ microsecond each when none is. ``engine.generate`` carries the request id
 
   engine.generate
     engine.lookup                  index.match_prefix
-    engine.fetch, engine.tail      hit: pool gather + kv_scatter_read, tail decode
+    engine.fetch                   hit: pool gather + kv_scatter_read
+    engine.tail                    hit: extend_fn over the tail, each chunk
+                                   (``tokens``: tail length, ``chunks``: calls)
     engine.prefill                 miss: prefill_fn
     engine.writeback               miss: kv_gather_write + pool write, with
       engine.allocate              pool.allocate
@@ -43,6 +46,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import RuntimeConfig
@@ -58,6 +62,10 @@ SPANS = (
     "engine.prefill", "engine.writeback", "engine.allocate", "engine.publish",
     "engine.first_token", "engine.decode", "engine.step", "engine.sync",
 )
+
+# Tokens a hit's tail is prefilled in per ``extend_fn`` call (capped at
+# ``max_len``): a multiple of the pool block, one call for tails up to it.
+TAIL_CHUNK = 256
 
 
 @dataclass
@@ -129,19 +137,13 @@ class RealEngine:
             with TraceAnnotation("engine.lookup"):
                 hits = self.index.match_prefix(prompt)
             n_hit = len(hits) * bt
-            info = {"hit_tokens": n_hit}
+            info = {"hit_tokens": n_hit, "tail_tokens": 0}
 
             if n_hit:
                 # --- pool fetch path: scatter-read hit blocks, skip prefill ---
                 with TraceAnnotation("engine.fetch"):
                     cache = self._fetch([b for _, b, _ in hits])
-                # step the tail through decode; if the prompt is fully covered,
-                # re-feed the last token (overwrites identical KV, yields logits)
-                with TraceAnnotation("engine.tail"):
-                    for t in range(min(n_hit, len(prompt) - 1), len(prompt)):
-                        logits, cache = self._decode(
-                            self.params, cache, jnp.asarray([prompt[t]]), jnp.asarray([t])
-                        )
+                logits, cache, info["tail_tokens"] = self._prefill_tail(prompt, n_hit, cache)
             else:
                 # --- prefill path + pool writeback ---
                 with TraceAnnotation("engine.prefill"):
@@ -168,6 +170,28 @@ class RealEngine:
             info["total_s"] = time.time() - t_start
             info["logits_finite"] = bool(ok)
         return out, info
+
+    def _prefill_tail(self, prompt: list[int], n_hit: int, cache: dict):
+        """Prefill ``prompt`` past its ``n_hit`` cached tokens into ``cache``,
+        in ``extend_fn`` calls of one chunk shape; if the prompt is fully
+        covered, re-feed its last token (same KV, yields logits). A window
+        that would run past ``max_len`` starts earlier instead, recomputing
+        cached positions with the same KV; the last one is padded past the
+        prompt, with KV that output decode overwrites before reading it.
+        Returns (last prompt token's logits, cache, tail tokens)."""
+        c = min(TAIL_CHUNK, self.max_len)
+        first = min(n_hit, len(prompt) - 1)
+        n_tail = len(prompt) - first
+        with TraceAnnotation("engine.tail", tokens=n_tail, chunks=-(-n_tail // c)):
+            for start in range(first, len(prompt), c):
+                start = min(start, self.max_len - c)
+                window = prompt[start : start + c]
+                tokens = np.zeros((1, c), np.int32)
+                tokens[0, : len(window)] = window
+                logits, cache = self._extend(
+                    self.params, cache, tokens, np.int32(start), np.int32(len(window))
+                )
+        return logits, cache, n_tail
 
     def _fetch(self, block_ids: list[int]) -> dict:
         """A decode cache of ``max_len`` slots holding the pool blocks
@@ -205,6 +229,10 @@ class RealEngine:
     @functools.cached_property
     def _decode(self):
         return jax.jit(self.model.decode_fn)
+
+    @functools.cached_property
+    def _extend(self):
+        return jax.jit(self.model.extend_fn)
 
     def _writeback(self, prompt: list[int], cache: dict) -> None:
         bt = self.pool.layout.block_tokens

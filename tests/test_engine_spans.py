@@ -82,6 +82,20 @@ def test_miss_and_hit_paths(traced):
         assert sum(s.name == "engine.sync" and s.req == r for s in spans) == N_OUT - 1
 
 
+def test_tail_span_counts_tokens_and_chunks(traced):
+    """The hit's tail is prefilled by ``extend_fn`` in ceil(T / C) chunks,
+    with no decode step before the first token."""
+    *_, spans, path = traced
+    data = jax.profiler.ProfileData.from_file(path)
+    stats = [dict(e.stats) for p in data.planes if p.name == "/host:CPU"
+             for ln in p.lines for e in ln.events if e.name == "engine.tail"]
+    chunk = min(real_runner.TAIL_CHUNK, 64)
+    assert [(s["tokens"], s["chunks"]) for s in stats] == [(8, -(-8 // chunk))]
+    tail = next(s for s in spans if s.name == "engine.tail")
+    assert not [s for s in spans if s.name == "engine.step"
+                and tail.start <= s.start and s.end <= tail.end]
+
+
 def test_served_programs_have_names():
     """The served path's device programs carry the names the benchmark's
     trace reduction looks up."""
@@ -89,19 +103,29 @@ def test_served_programs_have_names():
     prompt = list(range(40))
     batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
     assert "jit_prefill_fn" in eng._prefill.lower(eng.params, batch).as_text()
+    _, cache = eng._prefill(eng.params, batch)
+    chunk = np.zeros((1, min(real_runner.TAIL_CHUNK, 64)), np.int32)
+    lowered = eng._extend.lower(eng.params, cache, chunk, np.int32(32), np.int32(8))
+    assert "jit_extend_fn" in lowered.as_text()
     ids = jnp.arange(2, dtype=jnp.int32)
     data = eng.pool.data
     assert "jit_pool_gather" in real_runner.pool_gather.lower(data, ids).as_text()
     assert "jit_pool_write" in real_runner.pool_write.lower(data, ids, data[:2]).as_text()
 
 
-def test_output_length_compiles_nothing():
-    """The finiteness flag rides through the greedy pick: a new output
-    length runs the programs the first request compiled, and no other."""
+def _compiles() -> list:
+    """The names of the programs compiled from now on, as they compile."""
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda event, secs, **kw: compiles.append(kw.get("fun_name"))
         if event == "/jax/core/compile/backend_compile_duration" else None)
+    return compiles
+
+
+def test_output_length_compiles_nothing():
+    """The finiteness flag rides through the greedy pick: a new output
+    length runs the programs the first request compiled, and no other."""
+    compiles = _compiles()
     eng = RealEngine.create("olmo-1b", max_len=64, pool_blocks=16, kernel_mode="jnp")
     prompt = list(range(40))
     eng.generate(prompt, max_new=3)
@@ -110,4 +134,20 @@ def test_output_length_compiles_nothing():
     for max_new in (2, 5, 7):
         _, info = eng.generate(prompt, max_new=max_new)
         assert info["hit_tokens"] == 32 and info["logits_finite"]
+    assert compiles[n:] == []
+
+
+def test_tail_length_compiles_nothing():
+    """Every tail runs the one ``extend_fn`` shape the first hit compiled:
+    after it, hits with other tail lengths compile nothing."""
+    compiles = _compiles()
+    eng = RealEngine.create("olmo-1b", max_len=64, pool_blocks=16, kernel_mode="jnp")
+    prefix = list(range(32))
+    eng.generate(prefix, max_new=2)
+    eng.generate(prefix + [7] * 16, max_new=2)  # the first hit, a 16-token tail
+    assert "jit(extend_fn)" in compiles
+    n = len(compiles)
+    for n_tail in (0, 1, 5, 17, 29):
+        _, info = eng.generate(prefix + [9] * n_tail, max_new=2)
+        assert info["hit_tokens"] == 32 and info["tail_tokens"] == max(n_tail, 1)
     assert compiles[n:] == []

@@ -307,8 +307,8 @@ def test_real_engine_pool_reuse_is_exact():
 
 
 def test_real_engine_params_are_program_arguments():
-    """The weights reach the compiled prefill and decode as arguments of
-    ``main``, not as constants baked into the program."""
+    """The weights reach the compiled prefill, decode and tail extend as
+    arguments of ``main``, not as constants baked into the program."""
     import re
 
     import jax
@@ -329,3 +329,6 @@ def test_real_engine_params_are_program_arguments():
     tok = jnp.zeros((1,), jnp.int32)
     low = eng._decode.lower(eng.params, cache, tok, tok)
     assert n_main_args(low) == n_params + len(jax.tree.leaves(cache)) + 2
+    chunk = np.zeros((1, 64), np.int32)
+    low = eng._extend.lower(eng.params, cache, chunk, np.int32(32), np.int32(8))
+    assert n_main_args(low) == n_params + len(jax.tree.leaves(cache)) + 3

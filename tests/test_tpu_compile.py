@@ -69,9 +69,9 @@ def test_kv_transfer_kernel_compiles_for_v5e(one_chip, kernel, n_layers):
     assert _device_bytes(lowered.compile()) < HBM_BYTES
 
 
-def test_published_width_decode_step_compiles_for_v5e(one_chip):
-    """One decode step of qwen3-32b at published widths, one layer deep,
-    with the weights as arguments (shapes from ``jax.eval_shape``)."""
+def _one_layer_qwen3(one_chip):
+    """qwen3-32b at published widths, one layer deep; its weights as
+    shapes on the described chip (``jax.eval_shape``); a shape putter."""
     from repro.configs.base import RuntimeConfig
     from repro.models import Model
 
@@ -80,10 +80,34 @@ def test_published_width_decode_step_compiles_for_v5e(one_chip):
     on_chip = lambda t: jax.tree.map(  # noqa: E731
         lambda x: _sds(x.shape, x.dtype, one_chip), t
     )
-    params = on_chip(jax.eval_shape(model.init, jax.random.key(0)))
-    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 1024)))
-    tok = _sds((1,), jnp.int32, one_chip)
-    compiled = jax.jit(model.decode_fn).lower(params, cache, tok, tok).compile()
+    return model, on_chip(jax.eval_shape(model.init, jax.random.key(0))), on_chip
+
+
+def _assert_fits_with_weights_as_arguments(compiled, params):
     n_weight_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     assert compiled.memory_analysis().argument_size_in_bytes >= n_weight_bytes
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_published_width_decode_step_compiles_for_v5e(one_chip):
+    """One decode step of qwen3-32b at published widths, one layer deep,
+    with the weights as arguments (shapes from ``jax.eval_shape``)."""
+    model, params, on_chip = _one_layer_qwen3(one_chip)
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 1024)))
+    tok = _sds((1,), jnp.int32, one_chip)
+    compiled = jax.jit(model.decode_fn).lower(params, cache, tok, tok).compile()
+    _assert_fits_with_weights_as_arguments(compiled, params)
+
+
+def test_published_width_tail_extend_compiles_for_v5e(one_chip):
+    """One ``extend_fn`` call of qwen3-32b at published widths, one layer
+    deep: a 256-token chunk of a hit's tail over the shared-prefix cells'
+    8,512-slot cache, with the weights as arguments."""
+    from repro.serving.real_runner import TAIL_CHUNK
+
+    model, params, on_chip = _one_layer_qwen3(one_chip)
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 8512)))
+    tokens = _sds((1, TAIL_CHUNK), jnp.int32, one_chip)
+    scalar = _sds((), jnp.int32, one_chip)
+    compiled = jax.jit(model.extend_fn).lower(params, cache, tokens, scalar, scalar).compile()
+    _assert_fits_with_weights_as_arguments(compiled, params)

@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import wire
 from repro.core.index import GlobalIndex, PrefixHasher
@@ -219,6 +219,7 @@ def test_wire_batch_nesting_is_bounded():
 
 @settings(max_examples=30, deadline=None)
 @given(st.binary(min_size=0, max_size=200))
+@example(b"\x14\x00\x00\x00\x00")  # SEED_STATS cut short after its header
 def test_wire_fuzz_never_crashes(blob):
     """Arbitrary bytes either decode to a valid op or raise WireError."""
     pool = _pool(64)
