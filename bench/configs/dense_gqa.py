@@ -14,6 +14,10 @@ embeddings on the two halves of each head (cos/sin over head_dim/2
 frequencies theta^(-2i/head_dim)). Everything is float32 with matrix
 products at ``Precision.HIGHEST``.
 
+The counts (``params``, ``block_bytes``, ``token_flops``, ``logits_flops``)
+are what this architecture needs, whatever implements it; ``program_fields``
+is what the program's configuration has to read for these sizes.
+
 ``make_weights`` draws the weights from the seed in one jitted call, in
 the type they are served in and in the layout the program takes them in
 (the benchmark hands them to the engine). ``logits_at`` is the reference;
@@ -30,6 +34,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from bench.flops import BLOCK_TOKENS
+
+KV_BYTES = 2  # bf16 keys and values
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
@@ -52,6 +59,63 @@ def sizes_of(cfg: dict) -> dict:
         "theta": float(cfg["rope_theta"]),
         "eps": float(cfg["rms_norm_eps"]),
     }
+
+
+# sizes of a CPU test cell: the program's reduced widths
+TINY = {"hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 4,
+        "vocab_size": 256, "rope_theta": 10000.0, "rms_norm_eps": 1e-05}
+
+
+def program_fields(s: dict) -> dict:
+    """``RealEngine.cfg`` attribute -> the value it must hold for these sizes."""
+    return {
+        "n_layers": s["layers"], "d_model": s["d"], "n_heads": s["heads"],
+        "n_kv_heads": s["kv_heads"], "head_dim": s["head_dim"], "d_ff": s["ff"],
+        "vocab_size": s["vocab"], "rope_theta": s["theta"], "norm_eps": s["eps"],
+        "qkv_bias": False, "tie_embeddings": False, "act": "silu",
+    }
+
+
+# ---------------------------------------------------------------------------
+# counts: parameters, pool bytes, operations
+# ---------------------------------------------------------------------------
+
+
+def layer_params(s: dict) -> int:
+    d, hq, hkv, hd = s["d"], s["heads"], s["kv_heads"], s["head_dim"]
+    return 2 * d * hq * hd + 2 * d * hkv * hd + 3 * d * s["ff"] + 2 * d
+
+
+def params(s: dict) -> int:
+    """All weights: embedding table, layers, final norm and the head."""
+    return 2 * s["vocab"] * s["d"] + s["layers"] * layer_params(s) + s["d"]
+
+
+def block_bytes(s: dict) -> int:
+    """One pool block: keys and values of BLOCK_TOKENS tokens in every layer."""
+    return 2 * s["layers"] * BLOCK_TOKENS * s["kv_heads"] * s["head_dim"] * KV_BYTES
+
+
+def kv_bytes_per_token(s: dict) -> int:
+    return block_bytes(s) // BLOCK_TOKENS
+
+
+def token_flops(s: dict, context: int) -> float:
+    """One token through every layer with ``context`` keys to attend to
+    (itself included): 2 per multiply-add of the matrices, and of QK^T and
+    PV. The head is counted apart, by ``logits_flops``."""
+    attn = 4 * context * s["heads"] * s["head_dim"] * s["layers"]
+    return 2.0 * (s["layers"] * (layer_params(s) - 2 * s["d"])) + attn
+
+
+def logits_flops(s: dict) -> float:
+    return 2.0 * s["d"] * s["vocab"]
+
+
+# ---------------------------------------------------------------------------
+# weights
+# ---------------------------------------------------------------------------
 
 
 def weight_shapes(s: dict) -> dict:

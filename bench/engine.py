@@ -3,13 +3,22 @@
 The interface it relies on, and nothing else of the program:
 
 - ``RealEngine.create(arch, max_len=, pool_blocks=, seed=, kernel_mode=,
-  layers=)``, and its public fields ``cfg`` (sizes checked against the
-  configuration file), ``params`` (replaced by the benchmark's weights, in
-  the tree ``weight_shapes`` names) and ``pool`` (its ``layout.block_bytes``);
+  layers=)``, and its public fields ``cfg`` (every attribute the
+  architecture module's ``program_fields`` names must hold the value it
+  gives), ``params`` (replaced by the benchmark's weights, in the tree
+  ``weight_shapes`` names) and ``pool`` (its ``layout.block_bytes`` must
+  equal the module's ``block_bytes``);
 - ``RealEngine.generate(prompt, max_new) -> (tokens, info)`` with
   ``info["hit_tokens"]``, ``info["ttft_s"]`` (to the first token on the
   host), ``info["total_s"]`` and ``info["logits_finite"]``;
-- ``repro.launch.compile_cache.enable_compile_cache()``.
+- ``repro.launch.compile_cache.enable_compile_cache()``;
+- in a kept trace, read by ``bench/spans.py`` alone: the program's spans,
+  named in ``repro.serving.real_runner.SPANS`` (``engine.*``), and the
+  ``req`` stat on ``engine.generate``.
+
+From the architecture module (``bench/configs/<architecture>.py``) it takes
+``program_fields``, ``block_bytes`` and ``make_weights``: the layout checks
+live there, so a new form of the model or of its cache is new files only.
 """
 
 from __future__ import annotations
@@ -18,14 +27,7 @@ import gc
 
 import jax
 
-from bench import flops
-
-# configuration key -> RealEngine.cfg field
-_CFG_FIELDS = {
-    "layers": "n_layers", "d": "d_model", "heads": "n_heads",
-    "kv_heads": "n_kv_heads", "head_dim": "head_dim", "ff": "d_ff",
-    "vocab": "vocab_size", "theta": "rope_theta", "eps": "norm_eps",
-}
+_MISSING = "<missing>"
 
 
 def _shapes(tree) -> dict:
@@ -41,11 +43,11 @@ def build(program: dict, sizes: dict, arch, pool_blocks: int, max_len: int,
         program["arch"], max_len=max_len, pool_blocks=pool_blocks, seed=0,
         kernel_mode=kernel_mode, layers=program["layers"],
     )
-    wrong = {k: (getattr(eng.cfg, f), sizes[k]) for k, f in _CFG_FIELDS.items()
-             if getattr(eng.cfg, f) != sizes[k]}
-    if wrong or eng.cfg.qkv_bias or eng.cfg.tie_embeddings or eng.cfg.act != "silu":
+    wrong = {f: (getattr(eng.cfg, f, _MISSING), v) for f, v in arch.program_fields(sizes).items()
+             if getattr(eng.cfg, f, _MISSING) != v}
+    if wrong:
         raise SystemExit(f"program config differs from the configuration file: {wrong}")
-    if eng.pool.layout.block_bytes != flops.block_bytes(sizes):
+    if eng.pool.layout.block_bytes != arch.block_bytes(sizes):
         raise SystemExit("pool block layout differs from the configuration's")
     expected = _shapes(eng.params)
     eng.params = None  # free the program's own weights before making ours

@@ -10,6 +10,6 @@ def read(ctx):
     calls = ctx.calls_by_request("kv_gather_write")
     if not calls:
         return None
-    need = sum(flops.copy_bytes(ctx.sizes, r.prompt_len // flops.BLOCK_TOKENS)
+    need = sum(flops.copy_bytes(ctx.arch, ctx.sizes, r.prompt_len // flops.BLOCK_TOKENS)
                for r, _ in calls) / ctx.peak["hbm_bytes_per_s"]
     return 100.0 * need / sum(secs for _, secs in calls)

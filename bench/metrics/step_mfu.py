@@ -13,6 +13,6 @@ def read(ctx):
     busy = sum(r.end - r.start for r in recs)
     if not busy:
         return None
-    work = sum(flops.request_flops(ctx.sizes, r.prompt_len, r.hit_tokens, r.n_out)
+    work = sum(flops.request_flops(ctx.arch, ctx.sizes, r.prompt_len, r.hit_tokens, r.n_out)
                for r in recs)
     return 100.0 * work / busy / ctx.peak["bf16_flops_per_s"]

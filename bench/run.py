@@ -4,11 +4,34 @@
 
 Everything about a cell is found by name: ``BENCHMARK.json`` gives its
 configuration and traffic mix, ``bench/configs/<config>.json`` the sizes
-(and the architecture module beside it that makes the weights and holds
-the plain reference), ``bench/traffic/<mix>.json`` the traffic's
-distributions, ``bench/cells/<cell>.json`` the offered rate, the pool's size and
-the limit of the correctness check, ``bench/metrics/<metric>.py`` each
-per-layer metric, ``bench/peaks.json`` the chip's peaks.
+and the name of its architecture module, ``bench/traffic/<mix>.json`` the
+traffic's distributions, ``bench/cells/<cell>.json`` the offered rate, the
+pool's size and the limit of the correctness check,
+``bench/metrics/<metric>.py`` each per-layer metric, ``bench/peaks.json``
+the chip's peaks.
+
+The architecture module, ``bench/configs/<architecture>.py``, is the one
+place for whatever depends on the shape of the model; the rest of the
+harness holds no formula of any one architecture. It provides:
+
+- ``sizes_of(config) -> sizes``: the sizes it needs from the config file's
+  keys, as a flat dict that holds at least ``vocab`` (the traffic draws
+  token ids below it);
+- ``weight_shapes(sizes)`` and ``make_weights(sizes, seed)``: the weights
+  in the program's parameter tree, bf16, made on the device in one call;
+- ``logits_at(weights, sizes, tokens, read, pad_to, n_read, fp8=False)``:
+  the plain reference's logits, float32, nothing of the program; with
+  ``fp8=True`` the control, one precision below the configuration's;
+- ``program_fields(sizes)``: each ``RealEngine.cfg`` attribute and the
+  value it must hold, and ``block_bytes(sizes)``: the bytes of one pool
+  block of ``flops.BLOCK_TOKENS`` tokens; ``bench/engine.py`` refuses a
+  program that differs from either;
+- ``params(sizes)``, ``token_flops(sizes, context)`` and
+  ``logits_flops(sizes)``: parameters, and the operations of one token
+  through every layer with ``context`` positions to attend to and of its
+  logits. The counts are what the algorithm needs, whatever implements it;
+- ``TINY``: config keys that cut it to the program's reduced widths, for
+  the CPU tests (``bench/tests/tiny.py``).
 
 A run: set-up (weights made on the chip from the seed, shared prefixes
 published into the pool, every shape of the cell warmed), then an open
@@ -195,11 +218,12 @@ def end_to_end(records, seconds: float, t0: float) -> tuple[dict, dict]:
 class MetricContext:
     """What a per-layer metric reader sees."""
 
-    def __init__(self, records, summary, sizes, peak):
+    def __init__(self, records, summary, sizes, peak, arch):
         self.records = records
         self.trace = summary
         self.sizes = sizes
         self.peak = peak
+        self.arch = arch  # the architecture module: counts for the readers
 
     def program(self, name: str):
         if self.trace is None:
@@ -363,7 +387,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
         log(f"idle by host phase (s): {json.dumps(trace_lib.idle_by_phase(sm))}")
         log("programs (device s, calls): " + json.dumps(
             {k: [v["seconds"], v["calls"]] for k, v in sm.programs.items()}))
-        ctx = MetricContext(records, sm, cell.sizes, flops.peak(dev.device_kind))
+        ctx = MetricContext(records, sm, cell.sizes, flops.peak(dev.device_kind), cell.arch)
         for m in cell.per_layer:
             v = load_module(os.path.join(BENCH, "metrics", m["name"] + ".py")).read(ctx)
             if v is not None:

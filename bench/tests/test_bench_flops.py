@@ -9,9 +9,11 @@ import pytest
 
 from bench import flops, run
 
-SIZES = {c: run.Cell.load(w).sizes for c, w in (
+CELLS = {c: run.Cell.load(w) for c, w in (
     ("qwen3-32b-noqknorm-4L", "qwen3-32b-noqknorm-4L.shared-sysprompt"),
     ("internlm2-1.8b", "internlm2-1.8b.shared-sysprompt"))}
+SIZES = {c: cell.sizes for c, cell in CELLS.items()}
+DENSE = CELLS["internlm2-1.8b"].arch  # bench/configs/dense_gqa.py, as Cell.load loads it
 
 
 @pytest.mark.parametrize("config, n_params, block", [
@@ -20,16 +22,29 @@ SIZES = {c: run.Cell.load(w).sizes for c, w in (
     ("internlm2-1.8b", 1_889_110_016, 1_572_864),  # 48 fragments
 ])
 def test_params_and_block_bytes(config, n_params, block):
-    s = SIZES[config]
-    assert flops.params(s) == n_params
-    assert flops.block_bytes(s) == block
-    assert flops.kv_bytes_per_token(s) == block // 16
+    arch, s = CELLS[config].arch, SIZES[config]
+    assert arch.params(s) == n_params
+    assert arch.block_bytes(s) == block
+    assert arch.kv_bytes_per_token(s) == block // 16
+
+
+@pytest.mark.parametrize("config, prompt_len, hit, n_out, want", [
+    # the counts before they moved into the architecture module, pinned
+    ("qwen3-32b-noqknorm-4L", 8208, 8192, 64, 492968083456.0),  # a shared-prefix hit
+    ("qwen3-32b-noqknorm-4L", 8448, 8192, 8, 1325275807744.0),  # the longest tail
+    ("qwen3-32b-noqknorm-4L", 768, 0, 17, 3124933427200.0),  # a miss
+    ("internlm2-1.8b", 8208, 8192, 64, 390691553280.0),
+    ("internlm2-1.8b", 8448, 8192, 8, 1227682480128.0),
+    ("internlm2-1.8b", 768, 0, 17, 2434544959488.0),
+])
+def test_request_flops_pinned(config, prompt_len, hit, n_out, want):
+    assert flops.request_flops(CELLS[config].arch, SIZES[config], prompt_len, hit, n_out) == want
 
 
 @pytest.mark.parametrize("config", sorted(SIZES))
 def test_weights_match_the_program_tree(config):
     """The benchmark's weight layout is the program's parameter tree, leaf
-    for leaf, and holds flops.params() values (shapes only, no arrays)."""
+    for leaf, and holds the module's params() values (shapes only, no arrays)."""
     from repro.configs.base import RuntimeConfig
     from repro.configs.registry import get_config
     from repro.models import Model
@@ -42,21 +57,21 @@ def test_weights_match_the_program_tree(config):
     ours = jax.eval_shape(lambda: cell.arch.make_weights(cell.sizes, 0))
     shape = lambda t: jax.tree.map(lambda a: (a.shape, str(a.dtype)), t)  # noqa: E731
     assert shape(ours) == shape(theirs)
-    assert sum(a.size for a in jax.tree.leaves(ours)) == flops.params(cell.sizes)
+    assert sum(a.size for a in jax.tree.leaves(ours)) == cell.arch.params(cell.sizes)
 
 
 def test_request_flops_by_hand():
     s = {"layers": 1, "d": 4, "heads": 2, "kv_heads": 1, "head_dim": 2, "ff": 8, "vocab": 10}
     # weights a token multiplies: wq + wo (4x4 each), wk + wv (4x2 each), 3 MLP (4x8)
     mat = 2 * 4 * 4 + 2 * 4 * 2 + 3 * 4 * 8
-    assert flops.token_flops(s, 3) == 2 * mat + 4 * 3 * 2 * 2
+    assert DENSE.token_flops(s, 3) == 2 * mat + 4 * 3 * 2 * 2
     # a miss of 3 prompt tokens and 2 output tokens: positions 0..3, logits twice
     want = sum(2 * mat + 4 * c * 2 * 2 for c in (1, 2, 3, 4)) + 2 * (2 * 4 * 10)
-    assert flops.request_flops(s, 3, 0, 2) == want
+    assert flops.request_flops(DENSE, s, 3, 0, 2) == want
     # a full hit of 2 of the 3 prompt tokens computes positions 2..3
-    assert flops.request_flops(s, 3, 2, 2) == sum(
+    assert flops.request_flops(DENSE, s, 3, 2, 2) == sum(
         2 * mat + 4 * c * 2 * 2 for c in (3, 4)) + 2 * (2 * 4 * 10)
-    assert flops.copy_bytes(s, 3) == 2 * 3 * flops.block_bytes(s)
+    assert flops.copy_bytes(DENSE, s, 3) == 2 * 3 * DENSE.block_bytes(s)
 
 
 def test_peaks_by_device_kind():

@@ -150,7 +150,7 @@ def test_accepted_readers_on_recorded_spans(which):
     sm = trace.summarize(trace.load(os.path.join(FIXTURES, fname)))
     cell = tiny.tiny_cell(name)
     recs = [run.Record(0, 0, 1, n, m, 0, ok=True, hit_tokens=h, n_out=m) for n, h, m in reqs]
-    ctx = run.MetricContext(recs, sm, cell.sizes, flops.peak("TPU v5 lite"))
+    ctx = run.MetricContext(recs, sm, cell.sizes, flops.peak("TPU v5 lite"), cell.arch)
     for m in cell.per_layer:
         v = run.load_module(os.path.join(run.BENCH, "metrics", m["name"] + ".py")).read(ctx)
         assert v is not None and 0 < v < 100, m["name"]

@@ -81,7 +81,7 @@ def test_metrics_on_recorded_trace():
     cell = tiny.tiny_cell("qwen3-32b-noqknorm-4L.shared-sysprompt")
     recs = [run.Record(0, 0, 1, n, m, 0, ok=True, hit_tokens=32, n_out=m)
             for n, m in ((45, 2), (37, 3), (40, 4))]
-    ctx = run.MetricContext(recs, sm, cell.sizes, flops.peak("TPU v5 lite"))
+    ctx = run.MetricContext(recs, sm, cell.sizes, flops.peak("TPU v5 lite"), cell.arch)
     assert [r.prompt_len for r, _ in ctx.calls_by_request("kv_scatter_read")] == [45, 37, 40]
     for m in cell.per_layer:
         v = run.load_module(os.path.join(run.BENCH, "metrics", m["name"] + ".py")).read(ctx)

@@ -1,4 +1,5 @@
-"""A cell at the program's reduced CPU widths, for tests on the CPU."""
+"""A cell at the program's reduced CPU widths (its architecture module's
+``TINY``), for tests on the CPU."""
 
 from __future__ import annotations
 
@@ -6,9 +7,6 @@ import copy
 
 from bench import run
 
-TINY = {"hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
-        "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 4,
-        "vocab_size": 256, "rope_theta": 10000.0, "rms_norm_eps": 1e-05}
 MIXES = {
     "shared-sysprompt": {"prefixes": {"count": 2, "tokens": 32, "zipf_s": 1.0},
                          "prompt_tokens": {"dist": "log_uniform", "min": 4, "max": 16}},
@@ -24,7 +22,7 @@ def tiny_cell(name: str, n_requests: int = 3, seconds: float = 0.3,
     """The named cell with its sizes, lengths and rate cut to the CPU;
     everything else (metrics, limit, architecture module) as committed."""
     cell = run.Cell.load(name)
-    cfg = dict(cell.config, **TINY)
+    cfg = dict(cell.config, **cell.arch.TINY)
     cell = copy.copy(cell)
     cell.config = cfg
     cell.sizes = cell.arch.sizes_of(cfg)

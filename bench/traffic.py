@@ -136,7 +136,8 @@ def plan(mix: dict, rate_per_s: float, vocab: int, seed: int, seconds: float) ->
     prefix_len = pre["tokens"] if pre else 0
     max_len = -(-(prefix_len + hi_tail + hi_out) // BLOCK_TOKENS) * BLOCK_TOKENS
     if pre:
-        # a hit request steps its tail through decode: one shape for any tail
+        # a hit request prefills its tail in fixed chunks against the fetched
+        # cache: one compiled shape for any tail, warmed by one short tail
         warm = [request(0.0, 0, lo_tail, 2)]
         shapes = {
             "prefill_tokens": [prefix_len],
