@@ -26,10 +26,48 @@ class MoEConfig:
     capacity_factor: float = 1.25
     # Router softmax over experts; jitter etc. omitted (inference-focused).
     router_dtype: str = "float32"
+    # "softmax": softmax over the experts, top-k. "sigmoid" (DeepSeek-V3's
+    # noaux_tc): sigmoid scores; a correction bias steers selection only;
+    # the top ``topk_groups`` of ``n_groups`` groups (each scored by its two
+    # best) are kept, top-k inside them; weights are the chosen scores,
+    # normalized to sum 1, times ``routed_scaling``.
+    scoring: str = "softmax"
+    n_groups: int = 1
+    topk_groups: int = 1
+    routed_scaling: float = 1.0
+    n_shared: int = 0  # shared experts, each ``expert_ff`` wide, on every token
+    expert_ff: int = 0  # 0: the model's d_ff
+    # Expert parallelism: this chip holds experts [first_held, first_held +
+    # n_held) of the n_experts the router scores, and the layer adds only
+    # what they give. 0: all of them.
+    n_held: int = 0
+    first_held: int = 0
+
+    def __post_init__(self):
+        # the held share is computed by ``moe._held_share`` alone, which
+        # routes as "sigmoid" does; the softmax path indexes all experts
+        if self.n_held and self.scoring != "sigmoid":
+            raise ValueError("a held share of the experts needs scoring='sigmoid'")
 
     @property
     def enabled(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V3's ``rope_scaling``)."""
+
+    factor: float
+    original_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -78,6 +116,23 @@ class ModelConfig:
     tie_embeddings: bool = False
     act: str = "silu"  # silu | gelu
     dtype: str = "bfloat16"
+    # activations' dtype where it is not the weights' ``dtype``: "float32"
+    # keeps the residual stream and every activation in float32, matrix
+    # products taking the bf16 weights at ``Precision.HIGH``, so that a
+    # routed model picks the experts a float32 model picks
+    act_dtype: str = ""
+    # leading layers with a dense d_ff MLP before the MoE layers (DeepSeek-V3)
+    first_k_dense: int = 0
+    # multi-head latent attention (MLA), on when kv_lora_rank > 0: queries
+    # through a q_lora_rank bottleneck; one cached latent row a token and
+    # layer, kv_lora_rank wide, beside a rotary key qk_rope_head_dim wide
+    # shared by all heads
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: YarnConfig | None = None
     # --- notes for DESIGN.md §Arch-applicability / padding ---
     source: str = ""
 
@@ -85,6 +140,15 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head if self.d_head else self.d_model // self.n_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """One cached MLA row: the latent and the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def is_attention_free(self) -> bool:
@@ -111,7 +175,7 @@ class ModelConfig:
         if not self.moe.enabled:
             return []
         p = self.moe.layer_period
-        return [i for i in range(self.n_layers) if (i % p) == (p - 1)]
+        return [i for i in range(self.first_k_dense, self.n_layers) if (i % p) == (p - 1)]
 
     # ---------------- padding for TP divisibility -----------------------
     def padded_heads(self, tp: int) -> int:
@@ -138,6 +202,8 @@ class ModelConfig:
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         """KVCache bytes per token across all attention layers."""
         n_attn = len(self.attn_layer_ids())
+        if self.is_mla:
+            return n_attn * self.latent_width * dtype_bytes
         return n_attn * 2 * self.n_kv_heads * self.head_dim * dtype_bytes
 
     def ssm_state_bytes(self, dtype_bytes: int = 4) -> int:

@@ -5,7 +5,9 @@ Assigned architectures (10) + the paper's own evaluation model (qwen3-32b).
 
 from __future__ import annotations
 
-from repro.configs.base import ModelConfig, MoEConfig, SSMConfig
+import dataclasses
+
+from repro.configs.base import ModelConfig, MoEConfig, SSMConfig, YarnConfig
 
 # ---------------------------------------------------------------------------
 # Assigned architectures (exact configs from the assignment block).
@@ -182,6 +184,41 @@ LLAMA31_8B = ModelConfig(
     source="arXiv:2407.21783 (paper's transfer bench)",
 )
 
+# DeepSeek-V3 at its published widths as one chip's share of a layer split
+# over 32 chips by expert parallelism: this chip holds routed experts 0-7 of
+# 256; the router keeps its 256 outputs, 8 groups / top-4 and top-8. Multi-
+# token prediction is left out. ``n_layers=`` cuts the depth; the leading
+# dense layer counts once (``first_k_dense`` stays 1 below 3 layers' cut).
+# Activations are float32 (weights and the latent cache bf16): in bf16 a
+# held expert near the top-8 boundary enters or leaves the set on 0.5% of
+# (token, layer) pairs against a float32 model, which moves the logits as
+# much as float8 weights do.
+DEEPSEEK_V3_EP32 = ModelConfig(
+    name="deepseek-v3-ep32",
+    family="moe",
+    n_layers=61,
+    d_model=7168,
+    n_heads=128,
+    n_kv_heads=128,
+    d_ff=18432,
+    vocab_size=129280,
+    moe=MoEConfig(
+        n_experts=256, top_k=8, scoring="sigmoid", n_groups=8, topk_groups=4,
+        routed_scaling=2.5, n_shared=1, expert_ff=2048, n_held=8, first_held=0,
+    ),
+    first_k_dense=3,
+    q_lora_rank=1536,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    yarn=YarnConfig(factor=40.0, original_len=4096),
+    rope_theta=10000.0,
+    norm_eps=1e-6,
+    act_dtype="float32",
+    source="hf:deepseek-ai/DeepSeek-V3 config.json",
+)
+
 ASSIGNED: dict[str, ModelConfig] = {
     c.name: c
     for c in [
@@ -198,7 +235,9 @@ ASSIGNED: dict[str, ModelConfig] = {
     ]
 }
 
-EXTRA: dict[str, ModelConfig] = {c.name: c for c in [QWEN3_32B, LLAMA31_8B]}
+EXTRA: dict[str, ModelConfig] = {
+    c.name: c for c in [QWEN3_32B, LLAMA31_8B, DEEPSEEK_V3_EP32]
+}
 
 REGISTRY: dict[str, ModelConfig] = {**ASSIGNED, **EXTRA}
 
@@ -218,9 +257,17 @@ def get_config(name: str) -> ModelConfig:
 # ---------------------------------------------------------------------------
 
 
-def reduced_config(name: str) -> ModelConfig:
-    import dataclasses
+def cut_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """``cfg`` with ``n_layers`` layers; leading dense layers count once in
+    a cut, so the cut keeps every kind of layer."""
+    if n_layers == cfg.n_layers:
+        return cfg
+    return dataclasses.replace(
+        cfg, n_layers=n_layers, first_k_dense=min(cfg.first_k_dense, 1)
+    )
 
+
+def reduced_config(name: str) -> ModelConfig:
     cfg = get_config(name)
     n_layers = {  # keep topology periods intact
         "hybrid": 8,  # one full Jamba period (7 mamba + 1 attn), MoE alt
@@ -229,8 +276,19 @@ def reduced_config(name: str) -> ModelConfig:
     n_heads = 4 if cfg.n_heads else 0
     kv = min(cfg.n_kv_heads, 2) if cfg.n_kv_heads else 0
     moe = cfg.moe
-    if moe.enabled:
+    if moe.n_held:  # a share of the experts: route over 16, hold 4
+        moe = dataclasses.replace(
+            moe, n_experts=16, top_k=4, n_groups=4, topk_groups=2, expert_ff=32,
+            n_held=4, first_held=0,
+        )
+    elif moe.enabled:
         moe = dataclasses.replace(moe, n_experts=4, top_k=min(moe.top_k, 2))
+    mla = {}
+    if cfg.is_mla:
+        mla = dict(q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+                   qk_rope_head_dim=16, v_head_dim=16)
+    if cfg.first_k_dense:  # one dense layer, then two of the scan
+        n_layers = 3
     ssm = dataclasses.replace(cfg.ssm, d_state=16, head_dim=16, chunk_size=32)
     return dataclasses.replace(
         cfg,
@@ -245,4 +303,6 @@ def reduced_config(name: str) -> ModelConfig:
         moe=moe,
         ssm=ssm,
         n_frontend_tokens=min(cfg.n_frontend_tokens, 8),
+        first_k_dense=min(cfg.first_k_dense, 1),
+        **mla,
     )

@@ -42,23 +42,42 @@ from repro.core.locks import make_lock
 
 @dataclass(frozen=True)
 class PoolLayout:
-    """Byte layout of one pool block for a model config."""
+    """Byte layout of one pool block for a model config.
+
+    A layer stores ``n_parts`` fragments a block: a key/value pair of
+    ``n_kv_heads`` x ``head_dim`` rows (2), or one latent part of
+    ``head_dim``-wide rows (1, with one "head"; see ``latent``).
+    """
 
     block_tokens: int
     n_layers_kv: int  # attention layers
     n_kv_heads: int
     head_dim: int
     dtype_bytes: int = 2
+    n_parts: int = 2
+
+    @classmethod
+    def latent(cls, block_tokens: int, n_layers: int, width: int) -> "PoolLayout":
+        """One part a layer: a ``width``-wide latent row a token (MLA)."""
+        return cls(block_tokens, n_layers, 1, width, n_parts=1)
 
     @property
     def fragment_bytes(self) -> int:
-        """One (layer, k|v) fragment of a block (Qwen3-32B: 32 KiB)."""
+        """One (layer, part) fragment of a block (Qwen3-32B: 32 KiB)."""
         return self.block_tokens * self.n_kv_heads * self.head_dim * self.dtype_bytes
 
     @property
     def n_fragments(self) -> int:
-        """Fragments per block: 2 * n_layers (Qwen3-32B: 128)."""
-        return 2 * self.n_layers_kv
+        """Fragments per block: n_parts * n_layers (Qwen3-32B: 128)."""
+        return self.n_parts * self.n_layers_kv
+
+    @property
+    def row_shape(self) -> tuple[int, ...]:
+        """One token's row of a fragment in the device pool: (heads,
+        head_dim) for a key/value pair, (width,) for a latent part."""
+        if self.n_parts == 1:
+            return (self.n_kv_heads * self.head_dim,)
+        return (self.n_kv_heads, self.head_dim)
 
     @property
     def block_bytes(self) -> int:
@@ -70,6 +89,8 @@ class PoolLayout:
 
     @classmethod
     def for_model(cls, cfg: ModelConfig, block_tokens: int = 16) -> "PoolLayout":
+        if cfg.is_mla:
+            return cls.latent(block_tokens, cfg.n_layers, cfg.latent_width)
         return cls(
             block_tokens=block_tokens,
             n_layers_kv=max(1, len(cfg.attn_layer_ids())),
@@ -137,15 +158,9 @@ class BelugaPool:
         elif backing == "jax":
             import jax.numpy as jnp
 
-            # (n_blocks, 2*L, block_tokens, hkv, hd) device-side pool
+            # (n_blocks, n_parts*L, block_tokens, *row) device-side pool
             self.data = jnp.zeros(
-                (
-                    n_blocks,
-                    layout.n_fragments,
-                    layout.block_tokens,
-                    layout.n_kv_heads,
-                    layout.head_dim,
-                ),
+                (n_blocks, layout.n_fragments, layout.block_tokens, *layout.row_shape),
                 jnp.bfloat16,
             )
         else:

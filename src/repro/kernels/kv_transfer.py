@@ -5,7 +5,8 @@ fragments into ONE kernel launch; these are the TPU twins:
 
   * ``kv_gather_write``  — pack per-layer cache slots -> contiguous pool
     blocks (pool payload layout: (n_blocks, 2L, bt, hkv, hd), fragments
-    interleaved [k0, v0, k1, v1, ...]);
+    interleaved [k0, v0, k1, v1, ...]; or, for a latent cache with one
+    part a layer, (n_blocks, L, bt, width));
   * ``kv_scatter_read``  — pool blocks -> per-layer cache slots;
   * ``sparse_kv_gather`` — top-k token rows out of a token-major pool view
     (Exp #10: thousands of tiny pieces, one launch).
@@ -15,7 +16,7 @@ BlockSpec index_map dereferences them — data movement at memory semantics,
 no per-fragment request list (the RDMA sglist pathology this replaces).
 
 Grid shape: ONE step per pool block. Each step moves a fused
-(L, 2, bt, hkv, hd) fragment-pair block over the collapsed layer axis —
+(L, P, bt, *row) block of every layer's parts over the collapsed layer axis —
 a single fat DMA per pool block instead of an (n_blocks, L) grid of tiny
 (1, 1, bt, hkv, hd) copies, so grid/launch overhead is O(blocks), not
 O(blocks * layers).
@@ -34,53 +35,54 @@ from jax.experimental.pallas import tpu as pltpu
 # ---------------------------------------------------------------------------
 
 
-def _gather_write_body(slot_ref, k_ref, v_ref, o_ref):
-    # k_ref/v_ref: (L, 1, bt, hkv, hd) — every layer of one cache slot;
-    # o_ref: (1, L, 2, bt, hkv, hd) — one fused pool block, (k, v) paired
-    o_ref[0, :, 0] = k_ref[:, 0]
-    o_ref[0, :, 1] = v_ref[:, 0]
+def _gather_write_body(slot_ref, *refs):
+    # each part's ref: (L, 1, bt, *row) — every layer of one cache slot;
+    # o_ref: (1, L, P, bt, *row) — one fused pool block, the parts paired
+    *part_refs, o_ref = refs
+    for p, ref in enumerate(part_refs):
+        o_ref[0, :, p] = ref[:, 0]
 
 
 def kv_gather_write(
-    k_cache: jax.Array,  # (L, T, hkv, hd), T = n_slots * bt
-    v_cache: jax.Array,
+    k_cache: jax.Array,  # (L, T, *row), T = n_slots * bt
+    v_cache: jax.Array | None,  # like k_cache; None: one part a layer
     slot_ids: jax.Array,  # (n_blocks,) int32 block-aligned slots
     block_tokens: int,
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    L, T, hkv, hd = k_cache.shape
+    """Returns pool blocks (n_blocks, P*L, bt, *row), P = 2 parts (k, v)
+    or 1 part (a latent row of any width), fragments layer-major."""
+    parts = (k_cache,) if v_cache is None else (k_cache, v_cache)
+    L, T, *row = k_cache.shape
     n_blocks = slot_ids.shape[0]
     bt = block_tokens
     n_slots = T // bt
-    kc = k_cache.reshape(L, n_slots, bt, hkv, hd)
-    vc = v_cache.reshape(L, n_slots, bt, hkv, hd)
+    P = len(parts)
+    tail = (0,) * len(row)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec(
-                (L, 1, bt, hkv, hd),
-                lambda bi, slot_ref: (0, slot_ref[bi], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (L, 1, bt, hkv, hd),
-                lambda bi, slot_ref: (0, slot_ref[bi], 0, 0, 0),
-            ),
+                (L, 1, bt, *row),
+                lambda bi, slot_ref: (0, slot_ref[bi], 0, *tail),
+            )
+            for _ in parts
         ],
         out_specs=pl.BlockSpec(
-            (1, L, 2, bt, hkv, hd), lambda bi, slot_ref: (bi, 0, 0, 0, 0, 0)
+            (1, L, P, bt, *row), lambda bi, slot_ref: (bi, 0, 0, 0, *tail)
         ),
     )
     out = pl.pallas_call(
         _gather_write_body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks, L, 2, bt, hkv, hd), k_cache.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, L, P, bt, *row), k_cache.dtype),
         interpret=interpret,
-    )(slot_ids.astype(jnp.int32), kc, vc)
-    # (n_blocks, L, 2, ...) -> (n_blocks, 2L, ...) fragment-interleaved
-    return out.reshape(n_blocks, 2 * L, bt, hkv, hd)
+    )(slot_ids.astype(jnp.int32), *(c.reshape(L, n_slots, bt, *row) for c in parts))
+    # (n_blocks, L, P, ...) -> (n_blocks, P*L, ...) fragment-interleaved
+    return out.reshape(n_blocks, P * L, bt, *row)
 
 
 # ---------------------------------------------------------------------------
@@ -88,68 +90,58 @@ def kv_gather_write(
 # ---------------------------------------------------------------------------
 
 
-def _scatter_read_body(slot_ref, pool_ref, k0_ref, v0_ref, k_ref, v_ref):
-    # pool_ref: (1, L, 2, bt, hkv, hd); k_ref/v_ref: (L, 1, bt, hkv, hd).
-    # k0_ref/v0_ref are the zeroed outputs' aliases, left in HBM untouched.
-    del k0_ref, v0_ref
-    k_ref[:, 0] = pool_ref[0, :, 0]
-    v_ref[:, 0] = pool_ref[0, :, 1]
+def _scatter_read_body(slot_ref, pool_ref, *refs):
+    # pool_ref: (1, L, P, bt, *row); the P outputs: (L, 1, bt, *row) each.
+    # The first P refs are the zeroed outputs' aliases, left in HBM untouched.
+    outs = refs[len(refs) // 2:]
+    for p, ref in enumerate(outs):
+        ref[:, 0] = pool_ref[0, :, p]
 
 
 def kv_scatter_read(
-    pool_blocks: jax.Array,  # (n_blocks, 2L, bt, hkv, hd)
+    pool_blocks: jax.Array,  # (n_blocks, P*L, bt, *row)
     slot_ids: jax.Array,  # (n_blocks,) destination block-aligned slots
     n_slots: int,
     *,
+    n_parts: int = 2,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Returns (k_cache, v_cache) of shape (L, n_slots*bt, hkv, hd).
+) -> tuple[jax.Array, ...]:
+    """Returns one cache per part, (k_cache, v_cache) or (latent,), each of
+    shape (L, n_slots*bt, *row).
 
     Unwritten slots are zero: the outputs alias zero-filled buffers, and the
     grid writes only the mapped slots over them.
     """
-    n_blocks, twoL, bt, hkv, hd = pool_blocks.shape
-    L = twoL // 2
-    pool = pool_blocks.reshape(n_blocks, L, 2, bt, hkv, hd)
-    zeros = jnp.zeros((L, n_slots, bt, hkv, hd), pool_blocks.dtype)
+    n_blocks, PL, bt, *row = pool_blocks.shape
+    P = n_parts
+    L = PL // P
+    tail = (0,) * len(row)
+    pool = pool_blocks.reshape(n_blocks, L, P, bt, *row)
+    zeros = jnp.zeros((L, n_slots, bt, *row), pool_blocks.dtype)
+    out_spec = pl.BlockSpec(
+        (L, 1, bt, *row), lambda bi, slot_ref: (0, slot_ref[bi], 0, *tail)
+    )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec(
-                (1, L, 2, bt, hkv, hd),
-                lambda bi, slot_ref: (bi, 0, 0, 0, 0, 0),
+                (1, L, P, bt, *row),
+                lambda bi, slot_ref: (bi, 0, 0, 0, *tail),
             ),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (L, 1, bt, hkv, hd),
-                lambda bi, slot_ref: (0, slot_ref[bi], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (L, 1, bt, hkv, hd),
-                lambda bi, slot_ref: (0, slot_ref[bi], 0, 0, 0),
-            ),
-        ],
+        ] + [pl.BlockSpec(memory_space=pl.ANY)] * P,
+        out_specs=[out_spec] * P,
     )
-    k, v = pl.pallas_call(
+    outs = pl.pallas_call(
         _scatter_read_body,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((L, n_slots, bt, hkv, hd), pool_blocks.dtype),
-            jax.ShapeDtypeStruct((L, n_slots, bt, hkv, hd), pool_blocks.dtype),
-        ],
-        # operand indices count the scalar-prefetch slot ids: 2, 3 = zeros
-        input_output_aliases={2: 0, 3: 1},
+        out_shape=[jax.ShapeDtypeStruct((L, n_slots, bt, *row), pool_blocks.dtype)] * P,
+        # operand indices count the scalar-prefetch slot ids: 2.. = zeros
+        input_output_aliases={2 + p: p for p in range(P)},
         interpret=interpret,
-    )(slot_ids.astype(jnp.int32), pool, zeros, zeros)
-    return (
-        k.reshape(L, n_slots * bt, hkv, hd),
-        v.reshape(L, n_slots * bt, hkv, hd),
-    )
+    )(slot_ids.astype(jnp.int32), pool, *(zeros,) * P)
+    return tuple(o.reshape(L, n_slots * bt, *row) for o in outs)
 
 
 # ---------------------------------------------------------------------------

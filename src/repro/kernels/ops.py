@@ -61,6 +61,7 @@ def paged_attention(q, kv_pool, block_table, context_lens, *, mode="pallas"):
 
 @functools.partial(jax.jit, static_argnames=("block_tokens", "mode"))
 def kv_gather_write(k_cache, v_cache, slot_ids, block_tokens, *, mode="pallas"):
+    """Cache slots -> pool blocks; ``v_cache=None``: one part a layer."""
     use, interp = _use_pallas(mode)
     if use:
         return _kv.kv_gather_write(
@@ -69,17 +70,19 @@ def kv_gather_write(k_cache, v_cache, slot_ids, block_tokens, *, mode="pallas"):
     return _ref.kv_gather_write_ref(k_cache, v_cache, slot_ids, block_tokens)
 
 
-@functools.partial(jax.jit, static_argnames=("n_slots", "mode"))
-def kv_scatter_read(pool_blocks, slot_ids, n_slots, *, mode="pallas"):
+@functools.partial(jax.jit, static_argnames=("n_slots", "n_parts", "mode"))
+def kv_scatter_read(pool_blocks, slot_ids, n_slots, *, n_parts=2, mode="pallas"):
+    """Pool blocks -> one cache per part: (k, v), or (latent,) with n_parts=1."""
     use, interp = _use_pallas(mode)
     if use:
-        return _kv.kv_scatter_read(pool_blocks, slot_ids, n_slots, interpret=interp)
+        return _kv.kv_scatter_read(
+            pool_blocks, slot_ids, n_slots, n_parts=n_parts, interpret=interp
+        )
     bt = pool_blocks.shape[2]
-    L = pool_blocks.shape[1] // 2
-    hkv, hd = pool_blocks.shape[3], pool_blocks.shape[4]
-    k0 = jnp.zeros((L, n_slots * bt, hkv, hd), pool_blocks.dtype)
-    v0 = jnp.zeros_like(k0)
-    return _ref.kv_scatter_read_ref(pool_blocks, slot_ids, k0, v0, bt)
+    L = pool_blocks.shape[1] // n_parts
+    shape = (L, n_slots * bt, *pool_blocks.shape[3:])
+    zeros = tuple(jnp.zeros(shape, pool_blocks.dtype) for _ in range(n_parts))
+    return _ref.kv_scatter_read_ref(pool_blocks, slot_ids, zeros, bt)
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))

@@ -84,47 +84,44 @@ def paged_attention_ref(
 
 
 def kv_gather_write_ref(
-    k_cache: jax.Array,  # (L, T, hkv, hd) dense per-layer cache
-    v_cache: jax.Array,  # (L, T, hkv, hd)
+    k_cache: jax.Array,  # (L, T, *row) dense per-layer cache
+    v_cache: jax.Array | None,  # (L, T, *row); None: one part a layer
     slot_ids: jax.Array,  # (n_blocks,) int32: block-aligned slot index
     block_tokens: int,
 ) -> jax.Array:
-    """Returns pool payload (n_blocks, 2L, block_tokens, hkv, hd)."""
+    """Returns pool payload (n_blocks, P*L, block_tokens, *row)."""
+    parts = (k_cache,) if v_cache is None else (k_cache, v_cache)
     L = k_cache.shape[0]
 
     def one(slot):
         start = slot * block_tokens
-        kf = jax.lax.dynamic_slice_in_dim(k_cache, start, block_tokens, 1)
-        vf = jax.lax.dynamic_slice_in_dim(v_cache, start, block_tokens, 1)
-        # interleave (k_l, v_l) fragments: [k0, v0, k1, v1, ...]
-        kv = jnp.stack([kf, vf], axis=1)  # (L, 2, bt, hkv, hd)
-        return kv.reshape(2 * L, block_tokens, *kf.shape[2:])
+        frags = [jax.lax.dynamic_slice_in_dim(c, start, block_tokens, 1) for c in parts]
+        # interleave each layer's parts: [k0, v0, k1, v1, ...]
+        kv = jnp.stack(frags, axis=1)  # (L, P, bt, *row)
+        return kv.reshape(len(parts) * L, block_tokens, *frags[0].shape[2:])
 
     return jax.vmap(one)(slot_ids)
 
 
 def kv_scatter_read_ref(
-    pool_blocks: jax.Array,  # (n_blocks, 2L, bt, hkv, hd)
+    pool_blocks: jax.Array,  # (n_blocks, P*L, bt, *row)
     slot_ids: jax.Array,  # (n_blocks,) destination slots
-    k_cache: jax.Array,  # (L, T, hkv, hd) to scatter into
-    v_cache: jax.Array,
+    caches: tuple[jax.Array, ...],  # P caches (L, T, *row) to scatter into
     block_tokens: int,
-) -> tuple[jax.Array, jax.Array]:
-    n_blocks, twoL = pool_blocks.shape[0], pool_blocks.shape[1]
-    L = twoL // 2
-    kv = pool_blocks.reshape(n_blocks, L, 2, block_tokens, *pool_blocks.shape[3:])
+) -> tuple[jax.Array, ...]:
+    n_blocks, PL = pool_blocks.shape[0], pool_blocks.shape[1]
+    P = len(caches)
+    kv = pool_blocks.reshape(n_blocks, PL // P, P, block_tokens, *pool_blocks.shape[3:])
 
     def body(carry, i):
-        kc, vc = carry
         start = slot_ids[i] * block_tokens
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, kv[i, :, 0].astype(kc.dtype), start, 1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, kv[i, :, 1].astype(vc.dtype), start, 1)
-        return (kc, vc), None
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(c, kv[i, :, p].astype(c.dtype), start, 1)
+            for p, c in enumerate(carry)
+        ), None
 
-    (k_cache, v_cache), _ = jax.lax.scan(
-        body, (k_cache, v_cache), jnp.arange(n_blocks)
-    )
-    return k_cache, v_cache
+    caches, _ = jax.lax.scan(body, tuple(caches), jnp.arange(n_blocks))
+    return caches
 
 
 # ---------------------------------------------------------------------------

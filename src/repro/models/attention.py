@@ -139,12 +139,13 @@ def flash_attention(
 ) -> jax.Array:
     """Chunked (flash-style) attention with running softmax.
 
-    q: (b, sq, hq, d); k, v: (b, skv, hkv, d); GQA via on-the-fly repeat of
-    the kv chunk.  ``q_offset`` is the absolute position of q[:, 0] for
+    q, k: (b, s, h, d); v: (b, skv, hkv, dv), dv may differ from d (MLA);
+    GQA via on-the-fly repeat of the kv chunk.  ``q_offset`` is the absolute position of q[:, 0] for
     causal masking against the kv positions; ``kv_len`` masks a ragged tail.
     """
     b, sq_in, hq, d = q.shape
     _, skv_in, hkv, _ = k.shape
+    dv = v.shape[-1]
     n_rep = hq // hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
 
@@ -196,19 +197,19 @@ def flash_attention(
             )
             return (acc, m_new, l_new), None
 
-        acc0 = jnp.zeros((b, hq, chunk_q, d), jnp.float32)
+        acc0 = jnp.zeros((b, hq, chunk_q, dv), jnp.float32)
         m0 = jnp.full((b, hq, chunk_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hq, chunk_q), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(
             kv_step, (acc0, m0, l0), jnp.arange(nkv)
         )
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        return out.transpose(0, 2, 1, 3)  # (b, cq, hq, d)
+        return out.transpose(0, 2, 1, 3)  # (b, cq, hq, dv)
 
     outs = jax.lax.map(
         lambda args: per_q_chunk(args[0], args[1]), (jnp.arange(nq), qs)
-    )  # (nq, b, cq, hq, d)
-    out = outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, hq, d)
+    )  # (nq, b, cq, hq, dv)
+    out = outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, hq, dv)
     return out[:, :sq_in].astype(v.dtype)
 
 

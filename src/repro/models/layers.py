@@ -35,11 +35,20 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+    return rotate(x, positions, rope_freqs(x.shape[-1], theta))
+
+
+def rotate(x: jax.Array, positions: jax.Array, freqs: jax.Array,
+           scale: float = 1.0) -> jax.Array:
+    """Rotary embedding of the (first half, second half) pairs of x's last
+    dim at ``freqs`` (head_dim/2,); cos and sin times ``scale``.
+    x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
     half = x.shape[-1] // 2
-    freqs = rope_freqs(x.shape[-1], theta)  # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., s, half)
     cos = jnp.cos(angles)[..., None, :]  # (..., s, 1, half)
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [

@@ -9,6 +9,7 @@ exposes pure functions suitable for jit/lower: ``loss_fn``, ``prefill_fn``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -21,6 +22,22 @@ from repro.distributed.sharding import AxisRules
 from repro.models import transformer as stack_lib
 from repro.models.layers import embed_apply, norm_apply, norm_params, unembed_apply
 from repro.models.layers import embed_params
+
+
+def _act_precision(fn):
+    """Runs a model function at the matrix-product precision its
+    activations' dtype needs: float32 activations (``ModelConfig.act_dtype``)
+    at ``Precision.HIGH``, three bf16 passes, where a TPU's default would
+    round them to bf16."""
+
+    @functools.wraps(fn)
+    def wrapped(self, *args, **kwargs):
+        if self.cfg.act_dtype != "float32":
+            return fn(self, *args, **kwargs)
+        with jax.default_matmul_precision("high"):
+            return fn(self, *args, **kwargs)
+
+    return wrapped
 
 
 @dataclass
@@ -46,6 +63,8 @@ class Model:
             "stack": stack_lib.stack_params(cfg, self.tp),
             "final_ln": norm_params(cfg),
         }
+        if cfg.first_k_dense:
+            p["lead"] = stack_lib.stack_params(cfg, self.tp, lead=True)
         return p
 
     def init(self, key: jax.Array) -> dict:
@@ -67,18 +86,23 @@ class Model:
         if cfg.frontend == "audio_stub":
             x = batch["frame_embeds"].astype(cfg.dtype)
         elif cfg.frontend == "vision_stub":
-            tok_x = embed_apply(params["embed"], batch["tokens"], self.rules)
+            tok_x = self._embed_tokens(params, batch["tokens"])
             patch = batch["patch_embeds"].astype(cfg.dtype)
             x = jnp.concatenate([patch, tok_x], axis=1)
         else:
-            x = embed_apply(params["embed"], batch["tokens"], self.rules)
+            x = self._embed_tokens(params, batch["tokens"])
         b, s = x.shape[0], x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         return x, positions
 
+    def _embed_tokens(self, params: dict, tokens: jax.Array) -> jax.Array:
+        x = embed_apply(params["embed"], tokens, self.rules)
+        return x.astype(self.cfg.act_dtype) if self.cfg.act_dtype else x
+
     # ------------------------------------------------------------------
     # Training loss
     # ------------------------------------------------------------------
+    @_act_precision
     def loss_fn(self, params: dict, batch: dict) -> tuple[jax.Array, dict]:
         cfg = self.cfg
         x, positions = self.embed(params, batch)
@@ -117,6 +141,7 @@ class Model:
     # ------------------------------------------------------------------
     # Prefill: returns last-position logits + populated cache
     # ------------------------------------------------------------------
+    @_act_precision
     def prefill_fn(
         self, params: dict, batch: dict, max_len: int | None = None
     ) -> tuple[jax.Array, dict]:
@@ -134,6 +159,7 @@ class Model:
     # ------------------------------------------------------------------
     # Decode: one token for every sequence in the batch
     # ------------------------------------------------------------------
+    @_act_precision
     def decode_fn(
         self,
         params: dict,
@@ -147,7 +173,7 @@ class Model:
         if cfg.frontend == "audio_stub":
             x = embed_apply(params["embed"], tokens[:, None], self.rules)
         else:
-            x = embed_apply(params["embed"], tokens[:, None], self.rules)
+            x = self._embed_tokens(params, tokens[:, None])
         h, new_cache = stack_lib.decode_step_stack(
             params, cache, x, pos, cfg, self.runtime, self.rules,
             mesh=self.mesh,
@@ -161,6 +187,7 @@ class Model:
     # ------------------------------------------------------------------
     # Extend: prefill a chunk of tokens against a filled cache
     # ------------------------------------------------------------------
+    @_act_precision
     def extend_fn(
         self,
         params: dict,
@@ -175,7 +202,7 @@ class Model:
         no real token attends to it. Returns the (b, V) logits of row
         ``n_valid - 1`` and the updated cache."""
         cfg = self.cfg
-        x = embed_apply(params["embed"], tokens, self.rules)
+        x = self._embed_tokens(params, tokens)
         h, new_cache = stack_lib.extend_stack(
             params, cache, x, start, n_valid, cfg, self.runtime, self.rules
         )

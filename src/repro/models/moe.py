@@ -7,6 +7,8 @@ capacity-factor token dropping) — robust under GSPMD for the dry-run.  The
 Supports:
   * top-k routing (llama4-maverick top-1, arctic & jamba top-2)
   * Arctic's dense-residual MLP in parallel with the experts
+  * DeepSeek-V3: sigmoid scores, group-limited top-k, shared experts, and
+    a layer that holds a share of the experts (``_held_share``)
 """
 
 from __future__ import annotations
@@ -21,16 +23,21 @@ from repro.models.layers import act_fn, mlp_apply, mlp_params
 
 
 def moe_params(cfg: ModelConfig, tp: int) -> dict:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.n_experts
+    moe = cfg.moe
+    d, f, e = cfg.d_model, moe.expert_ff or cfg.d_ff, moe.n_experts
     dt = cfg.dtype
     p = {
         "router": ParamSpec((d, e), "float32", ("embed", "experts")),
-        "wi_gate": ParamSpec((e, d, f), dt, ("experts", "embed", "expert_mlp")),
-        "wi_up": ParamSpec((e, d, f), dt, ("experts", "embed", "expert_mlp")),
-        "wo": ParamSpec((e, f, d), dt, ("experts", "expert_mlp", "embed")),
+        "wi_gate": ParamSpec((moe.held, d, f), dt, ("experts", "embed", "expert_mlp")),
+        "wi_up": ParamSpec((moe.held, d, f), dt, ("experts", "embed", "expert_mlp")),
+        "wo": ParamSpec((moe.held, f, d), dt, ("experts", "expert_mlp", "embed")),
     }
-    if cfg.moe.dense_residual:
-        p["dense"] = mlp_params(cfg, cfg.moe.dense_residual_ff)
+    if moe.scoring == "sigmoid":  # selection bias (e_score_correction_bias)
+        p["router_bias"] = ParamSpec((e,), "float32", ("experts",), init="zeros")
+    if moe.n_shared:
+        p["shared"] = mlp_params(cfg, moe.n_shared * f)
+    if moe.dense_residual:
+        p["dense"] = mlp_params(cfg, moe.dense_residual_ff)
     return p
 
 
@@ -49,6 +56,8 @@ def moe_apply(
 ) -> tuple[jax.Array, dict]:
     b, s, d = x.shape
     moe = cfg.moe
+    if moe.scoring == "sigmoid":
+        return _held_share(p, x, cfg, rules), {"load_balance_loss": 0.0}
 
     # a2a pays off when there are enough tokens per shard to fill the
     # all-to-all buffers; decode-sized batches fall back to einsum dispatch
@@ -317,3 +326,52 @@ def _ragged_dispatch(p, xt, top_w, top_e, cfg, rules):
     if rules is not None:
         out = constrain(out, rules, ("batch", "act_embed"))
     return out
+
+
+def route_grouped(logits: jax.Array, bias: jax.Array, moe) -> jax.Array:
+    """DeepSeek-V3's ``noaux_tc`` routing, float32. logits: (t, E) ->
+    (t, E) weights, zero but for each token's top-k experts: sigmoid
+    scores; ``bias`` shifts them for selection only; each group is scored
+    by the sum of its two best biased scores and the top ``topk_groups``
+    groups are kept; top-k experts inside them; weights are the unbiased
+    scores of those, normalized to sum 1, times ``routed_scaling``."""
+    t, e = logits.shape
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = scores + bias
+    groups = choice.reshape(t, moe.n_groups, e // moe.n_groups)
+    group_score = jax.lax.top_k(groups, 2)[0].sum(-1)  # (t, n_groups)
+    _, keep = jax.lax.top_k(group_score, moe.topk_groups)
+    rows = jnp.arange(t)[:, None]
+    kept = jnp.zeros((t, moe.n_groups), jnp.bool_).at[rows, keep].set(True)
+    choice = jnp.where(jnp.repeat(kept, e // moe.n_groups, axis=1), choice, -jnp.inf)
+    _, top = jax.lax.top_k(choice, moe.top_k)
+    w = jnp.take_along_axis(scores, top, axis=1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * moe.routed_scaling
+    return jnp.zeros((t, e), jnp.float32).at[rows, top].set(w)
+
+
+def _held_share(p, x, cfg: ModelConfig, rules) -> jax.Array:
+    """One chip's share of an expert-parallel layer: route over all
+    ``n_experts``, add what the held experts give for the tokens routed to
+    them (every held expert runs on every token, at weight 0 where not
+    routed), and the shared experts. What absent experts would add is left
+    out. x: (b, s, d)."""
+    moe = cfg.moe
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    act = act_fn(cfg.act)
+    with jax.named_scope("moe.route"):
+        # float32 logits: at default precision a TPU would round the router's
+        # operands to bfloat16 and flip near-tied experts
+        logits = jnp.dot(xt.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST)
+        w = route_grouped(logits, p["router_bias"], moe)
+        w = w[:, moe.first_held : moe.first_held + moe.held]  # (t, held)
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("td,edf->tef", xt, p["wi_gate"])
+        u = jnp.einsum("td,edf->tef", xt, p["wi_up"])
+        h = act(g) * u * w[..., None].astype(xt.dtype)
+        out = jnp.einsum("tef,efd->td", h, p["wo"], preferred_element_type=jnp.float32)
+    if moe.n_shared:
+        with jax.named_scope("moe.shared"):
+            out = out + mlp_apply(p["shared"], xt, cfg, None)
+    return out.reshape(b, s, d).astype(x.dtype)

@@ -9,10 +9,15 @@ A "period" is the smallest repeating pattern of layers:
 Params for each position-in-period are stacked with a leading (n_periods,)
 dim and consumed as scan xs — one compiled layer body regardless of depth
 (the roofline analyzer multiplies while-loop bodies by their trip count).
+
+Leading dense layers (DeepSeek-V3's ``first_k_dense``) come before the
+periods as a scan of their own: params and cache under ``"lead"``, laid out
+as one period of kind (mixer, "mlp").
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -22,13 +27,14 @@ from repro.configs.base import ModelConfig, RuntimeConfig
 from repro.distributed.sharding import AxisRules, ParamSpec, constrain, is_param_spec
 from repro.models import attention as attn_lib
 from repro.models import mamba as mamba_lib
+from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
 from repro.models.layers import mlp_apply, mlp_params, norm_apply, norm_params
 
 
 @dataclass(frozen=True)
 class LayerKind:
-    mixer: str  # "attn" | "ssm"
+    mixer: str  # "attn" | "mla" | "ssm"
     ffn: str  # "mlp" | "moe" | "none"
 
 
@@ -59,14 +65,46 @@ def layer_kinds(cfg: ModelConfig) -> list[LayerKind]:
             ffn = "moe" if (p > 1 and pos in moe_ids) or (p == 1) else "mlp"
         else:
             ffn = "mlp"
+        if mixer == "attn" and cfg.is_mla:
+            mixer = "mla"
         kinds.append(LayerKind(mixer=mixer, ffn=ffn))
     return kinds
 
 
+def lead_kinds(cfg: ModelConfig) -> list[LayerKind]:
+    """The one position of the leading dense layers' scan (none if none)."""
+    if not cfg.first_k_dense:
+        return []
+    return [LayerKind(mixer=layer_kinds(cfg)[0].mixer, ffn="mlp")]
+
+
 def n_periods(cfg: ModelConfig) -> int:
     p = period_length(cfg)
-    assert cfg.n_layers % p == 0, (cfg.n_layers, p)
-    return cfg.n_layers // p
+    n = cfg.n_layers - cfg.first_k_dense
+    assert n % p == 0, (n, p)
+    return n // p
+
+
+def _segments(cfg: ModelConfig, params: dict, cache: dict | None):
+    """Each scanned run of layers in order: (name, kinds, its params, its
+    cache). The leading dense layers' params and cache sit under "lead";
+    the periods' params under "stack", their cache at the top level."""
+    rest = cache
+    out = []
+    if cfg.first_k_dense:
+        rest = None if cache is None else {k: v for k, v in cache.items() if k != "lead"}
+        out.append(("lead", lead_kinds(cfg), params["lead"],
+                    None if cache is None else cache["lead"]))
+    out.append(("stack", layer_kinds(cfg), params["stack"], rest))
+    return out
+
+
+def _join(caches: dict) -> dict:
+    """Per-segment caches -> the cache tree (see ``_segments``)."""
+    out = dict(caches["stack"])
+    if "lead" in caches:
+        out["lead"] = caches["lead"]
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -85,6 +123,8 @@ def _position_params(cfg: ModelConfig, kind: LayerKind, tp: int) -> dict:
     p: dict = {"ln1": norm_params(cfg)}
     if kind.mixer == "attn":
         p["attn"] = attn_lib.attn_params(cfg, tp)
+    elif kind.mixer == "mla":
+        p["attn"] = mla_lib.mla_params(cfg)
     else:
         p["ssm"] = mamba_lib.mamba_params(cfg, tp)
     if kind.ffn != "none":
@@ -96,9 +136,10 @@ def _position_params(cfg: ModelConfig, kind: LayerKind, tp: int) -> dict:
     return p
 
 
-def stack_params(cfg: ModelConfig, tp: int) -> dict:
-    np_ = n_periods(cfg)
-    kinds = layer_kinds(cfg)
+def stack_params(cfg: ModelConfig, tp: int, lead: bool = False) -> dict:
+    """The periods' stacked params; ``lead=True``: the leading dense layers'."""
+    np_ = cfg.first_k_dense if lead else n_periods(cfg)
+    kinds = lead_kinds(cfg) if lead else layer_kinds(cfg)
     out = {}
     for pos, kind in enumerate(kinds):
         sub = _position_params(cfg, kind, tp)
@@ -126,15 +167,29 @@ def cache_specs(
     kv_axes: logical axes for the (batch, seq) dims of the kv cache, e.g.
     ("batch", "kv_seq") for decode_32k or (None, "kv_seq_long") for long_500k.
     """
-    np_ = n_periods(cfg)
-    kinds = layer_kinds(cfg)
+    out = _cache_specs(cfg, layer_kinds(cfg), n_periods(cfg), batch, max_len, kv_axes, kv_dtype)
+    if cfg.first_k_dense:
+        out["lead"] = _cache_specs(
+            cfg, lead_kinds(cfg), cfg.first_k_dense, batch, max_len, kv_axes, kv_dtype
+        )
+    return out
+
+
+def _cache_specs(cfg, kinds, np_, batch, max_len, kv_axes, kv_dtype) -> dict:
     di, nh, conv_dim = (0, 0, 0)
     if cfg.has_ssm_layers:
         di, nh, conv_dim = mamba_lib.ssm_dims(cfg)
     out = {}
     b_ax, s_ax = kv_axes
     for pos, kind in enumerate(kinds):
-        if kind.mixer == "attn":
+        if kind.mixer == "mla":
+            out[f"pos_{pos}"] = {"latent": ParamSpec(
+                (np_, batch, max_len, cfg.latent_width),
+                kv_dtype or cfg.dtype,
+                ("layers", b_ax, s_ax, None),
+                init="zeros",
+            )}
+        elif kind.mixer == "attn":
             kv = ParamSpec(
                 (np_, batch, max_len, cfg.n_kv_heads, cfg.head_dim),
                 kv_dtype or cfg.dtype,
@@ -169,6 +224,10 @@ def _mixer_full(pos_params, kind, x, positions, cfg, runtime, rules,
                 collect_cache: bool, max_len: int | None):
     """Full-sequence mixer (train / prefill). Returns (out, cache_entry)."""
     h = norm_apply(pos_params["ln1"], x, cfg)
+    if kind.mixer == "mla":
+        return mla_lib.mla_full(
+            pos_params["attn"], h, positions, cfg, runtime, collect_cache, max_len
+        )
     if kind.mixer == "attn":
         q, k, v = attn_lib.qkv_proj(pos_params["attn"], h, cfg, positions, rules)
         o = attn_lib.flash_attention(
@@ -218,9 +277,8 @@ def forward_full(
     max_len: int | None = None,
 ):
     """Run the full stack; returns (hidden, aux_loss, cache|None)."""
-    kinds = layer_kinds(cfg)
 
-    def period_fn(carry, xs_params):
+    def period_fn(kinds, carry, xs_params):
         h, aux = carry
         caches = {}
         for pos, kind in enumerate(kinds):
@@ -238,16 +296,18 @@ def forward_full(
             aux = aux + lb
         return (h, aux), caches if collect_cache else None
 
-    body = period_fn
-    if runtime.remat != "none":
-        policy = {
-            "full": jax.checkpoint_policies.nothing_saveable,
-            "dots": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-        }[runtime.remat]
-        body = jax.checkpoint(period_fn, policy=policy, prevent_cse=False)
-
-    (h, aux), caches = jax.lax.scan(body, (x, 0.0), params["stack"])
-    return h, aux, caches
+    carry, caches = (x, 0.0), {}
+    for name, kinds, seg_params, _ in _segments(cfg, params, None):
+        body = functools.partial(period_fn, kinds)
+        if runtime.remat != "none":
+            policy = {
+                "full": jax.checkpoint_policies.nothing_saveable,
+                "dots": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+            }[runtime.remat]
+            body = jax.checkpoint(body, policy=policy, prevent_cse=False)
+        carry, caches[name] = jax.lax.scan(body, carry, seg_params)
+    h, aux = carry
+    return h, aux, _join(caches) if collect_cache else None
 
 
 def decode_step_stack(
@@ -263,10 +323,9 @@ def decode_step_stack(
     kv_batch_axes: tuple[str, ...] = (),
 ):
     """One decode token through the stack; returns (hidden, new_cache)."""
-    kinds = layer_kinds(cfg)
     cache_len = pos + 1
 
-    def period_fn(carry, xs):
+    def period_fn(kinds, carry, xs):
         h = carry
         pp, pc = xs
         new_caches = {}
@@ -274,7 +333,11 @@ def decode_step_stack(
             layer_p = pp[f"pos_{p_i}"]
             layer_c = pc[f"pos_{p_i}"]
             hn = norm_apply(layer_p["ln1"], h, cfg)
-            if kind.mixer == "attn":
+            if kind.mixer == "mla":
+                mix_out, new_caches[f"pos_{p_i}"] = mla_lib.mla_decode(
+                    layer_p["attn"], hn, layer_c, pos, cfg
+                )
+            elif kind.mixer == "attn":
                 q, k_new, v_new = attn_lib.qkv_proj(
                     layer_p["attn"], hn, cfg, pos[:, None], rules
                 )
@@ -302,8 +365,11 @@ def decode_step_stack(
                 h = constrain(h, rules, ("batch", "seq", "act_embed"))
         return h, new_caches
 
-    h, new_cache = jax.lax.scan(period_fn, x, (params["stack"], cache))
-    return h, new_cache
+    h, new = x, {}
+    for name, kinds, seg_params, seg_cache in _segments(cfg, params, cache):
+        body = functools.partial(period_fn, kinds)
+        h, new[name] = jax.lax.scan(body, h, (seg_params, seg_cache))
+    return h, _join(new)
 
 
 def extend_stack(
@@ -318,15 +384,16 @@ def extend_stack(
 ):
     """A chunk of tokens at positions ``start … start+c-1`` through an
     attention stack whose cache already holds the positions before
-    ``start``: each layer writes the chunk's K/V into its cache at ``start``,
-    then attends causally over the cache, masked beyond ``start + n_valid``.
-    Returns (hidden, new_cache)."""
-    kinds = layer_kinds(cfg)
-    assert all(kind.mixer == "attn" for kind in kinds), "extend needs an attention stack"
+    ``start``: each layer writes the chunk's K/V (or latent rows) into its
+    cache at ``start``, then attends causally over the cache, masked beyond
+    ``start + n_valid``. Returns (hidden, new_cache)."""
+    assert all(k.mixer in ("attn", "mla") for k in layer_kinds(cfg)), (
+        "extend needs an attention stack"
+    )
     b, c = x.shape[0], x.shape[1]
     positions = jnp.broadcast_to(start + jnp.arange(c, dtype=jnp.int32), (b, c))
 
-    def period_fn(carry, xs):
+    def period_fn(kinds, carry, xs):
         h = carry
         pp, pc = xs
         new_caches = {}
@@ -334,24 +401,35 @@ def extend_stack(
             layer_p = pp[f"pos_{p_i}"]
             layer_c = pc[f"pos_{p_i}"]
             hn = norm_apply(layer_p["ln1"], h, cfg)
-            q, k_new, v_new = attn_lib.qkv_proj(layer_p["attn"], hn, cfg, positions, rules)
-            kc = jax.lax.dynamic_update_slice_in_dim(
-                layer_c["k"], k_new.astype(layer_c["k"].dtype), start, axis=1
-            )
-            vc = jax.lax.dynamic_update_slice_in_dim(
-                layer_c["v"], v_new.astype(layer_c["v"].dtype), start, axis=1
-            )
-            o = attn_lib.flash_attention(
-                q, attn_lib._dequant(kc), attn_lib._dequant(vc), causal=True,
-                q_offset=start, kv_len=start + n_valid,
-                chunk_q=runtime.attn_chunk_q, chunk_kv=runtime.attn_chunk_kv,
-            )
-            h = h + attn_lib.out_proj(layer_p["attn"], o, rules)
+            if kind.mixer == "mla":
+                mix_out, new_caches[f"pos_{p_i}"] = mla_lib.mla_extend(
+                    layer_p["attn"], hn, layer_c, start, n_valid, positions, cfg
+                )
+            else:
+                q, k_new, v_new = attn_lib.qkv_proj(
+                    layer_p["attn"], hn, cfg, positions, rules
+                )
+                kc = jax.lax.dynamic_update_slice_in_dim(
+                    layer_c["k"], k_new.astype(layer_c["k"].dtype), start, axis=1
+                )
+                vc = jax.lax.dynamic_update_slice_in_dim(
+                    layer_c["v"], v_new.astype(layer_c["v"].dtype), start, axis=1
+                )
+                o = attn_lib.flash_attention(
+                    q, attn_lib._dequant(kc), attn_lib._dequant(vc), causal=True,
+                    q_offset=start, kv_len=start + n_valid,
+                    chunk_q=runtime.attn_chunk_q, chunk_kv=runtime.attn_chunk_kv,
+                )
+                mix_out = attn_lib.out_proj(layer_p["attn"], o, rules)
+                new_caches[f"pos_{p_i}"] = {"k": kc, "v": vc}
+            h = h + mix_out
             h, _ = _ffn(layer_p, kind, h, cfg, runtime, rules)
             if rules is not None:
                 h = constrain(h, rules, ("batch", "seq", "act_embed"))
-            new_caches[f"pos_{p_i}"] = {"k": kc, "v": vc}
         return h, new_caches
 
-    h, new_cache = jax.lax.scan(period_fn, x, (params["stack"], cache))
-    return h, new_cache
+    h, new = x, {}
+    for name, kinds, seg_params, seg_cache in _segments(cfg, params, cache):
+        body = functools.partial(period_fn, kinds)
+        h, new[name] = jax.lax.scan(body, h, (seg_params, seg_cache))
+    return h, _join(new)

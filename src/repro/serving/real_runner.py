@@ -11,10 +11,12 @@ numerics and REAL pool reuse —
         prefilled against the fetched cache by ``extend_fn``, in chunks of
         ``TAIL_CHUNK`` tokens (one compiled shape for any tail).
 
-Restricted to homogeneous attention stacks (period-1 archs: olmo, qwen,
-command-r, internlm2, musicgen, internvl2 backbones). Hybrid/ssm archs
-would need their recurrent state pooled beside the KV blocks, which the
-pool does not do yet; they run only in the simulated cluster.
+Restricted to stacks of attention layers: dense GQA (olmo, qwen,
+command-r, internlm2, ...) with a key/value pair a layer in each pool
+block, and latent attention (deepseek-v3-ep32: a leading dense layer, then
+MoE layers) with one latent part a layer. Hybrid/ssm archs would need their
+recurrent state pooled beside the KV blocks, which the pool does not do
+yet; they run only in the simulated cluster.
 
 ``generate`` writes a span (``jax.profiler.TraceAnnotation``) at each
 layer boundary, named in ``SPANS``: on the profiler's host plane, on the
@@ -25,6 +27,7 @@ microsecond each when none is. ``engine.generate`` carries the request id
   engine.generate
     engine.lookup                  index.match_prefix
     engine.fetch                   hit: pool gather + kv_scatter_read
+                                   (``blocks`` fetched, their ``bytes``)
     engine.tail                    hit: extend_fn over the tail, each chunk
                                    (``tokens``: tail length, ``chunks``: calls)
     engine.prefill                 miss: prefill_fn
@@ -50,12 +53,11 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import RuntimeConfig
-from repro.configs.registry import get_config, reduced_config
+from repro.configs.registry import cut_depth, get_config, reduced_config
 from repro.core.index import GlobalIndex
 from repro.core.pool import BelugaPool, PoolLayout
 from repro.kernels import ops
 from repro.models import Model
-from repro.models import transformer as stack_lib
 
 SPANS = (
     "engine.generate", "engine.lookup", "engine.fetch", "engine.tail",
@@ -90,23 +92,18 @@ class RealEngine:
         layers: int | None = None,
     ) -> "RealEngine":
         """``layers=None``: the reduced CPU config; ``layers=n``: the
-        published widths with the depth cut to ``n`` layers."""
+        published widths with the depth cut to ``n`` layers (leading dense
+        layers count once)."""
         if layers is None:
             cfg = reduced_config(arch)
         else:
-            cfg = dataclasses.replace(get_config(arch), n_layers=layers)
-        assert stack_lib.period_length(cfg) == 1 and cfg.n_heads > 0, (
-            "RealEngine needs a homogeneous attention stack"
-        )
+            cfg = cut_depth(get_config(arch), layers)
+        if cfg.has_ssm_layers or not cfg.n_heads:
+            raise ValueError(f"RealEngine needs a stack of attention layers, not {cfg.name}")
         model = Model(cfg, RuntimeConfig(remat="none", decode_kv="replicated"))
         params = jax.jit(model.init)(jax.random.key(seed))
-        layout = PoolLayout(
-            block_tokens=16,
-            n_layers_kv=cfg.n_layers,
-            n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim,
-        )
-        pool = BelugaPool(layout, n_blocks=pool_blocks, n_shards=8, backing="jax")
+        pool = BelugaPool(PoolLayout.for_model(cfg), n_blocks=pool_blocks, n_shards=8,
+                          backing="jax")
         return cls(
             cfg=cfg,
             model=model,
@@ -118,14 +115,28 @@ class RealEngine:
         )
 
     # ------------------------------------------------------------------
-    def _cache_to_layers(self, cache: dict) -> tuple[jax.Array, jax.Array]:
-        """(L, 1, T, hkv, hd) stacked cache -> (L, T, hkv, hd)."""
-        k = cache["pos_0"]["k"][:, 0]
-        v = cache["pos_0"]["v"][:, 0]
-        return k, v
+    @property
+    def _parts(self) -> tuple[str, ...]:
+        """The cache's parts a layer, in pool-block order."""
+        return ("latent",) if self.cfg.is_mla else ("k", "v")
 
-    def _layers_to_cache(self, k: jax.Array, v: jax.Array) -> dict:
-        return {"pos_0": {"k": k[:, None], "v": v[:, None]}}
+    def _cache_to_layers(self, cache: dict) -> tuple[jax.Array, ...]:
+        """Stacked cache -> one array per part, (L, T, *row) over every
+        layer in order (the leading dense layers first)."""
+        if "lead" not in cache:
+            return tuple(cache["pos_0"][p][:, 0] for p in self._parts)
+        return tuple(
+            jnp.concatenate([cache["lead"]["pos_0"][p][:, 0], cache["pos_0"][p][:, 0]])
+            for p in self._parts
+        )
+
+    def _layers_to_cache(self, *parts: jax.Array) -> dict:
+        k = self.cfg.first_k_dense
+        cache = {"pos_0": {p: x[k:, None] if k else x[:, None]
+                           for p, x in zip(self._parts, parts)}}
+        if k:
+            cache["lead"] = {"pos_0": {p: x[:k, None] for p, x in zip(self._parts, parts)}}
+        return cache
 
     # ------------------------------------------------------------------
     def generate(self, prompt: list[int], max_new: int = 16) -> tuple[list[int], dict]:
@@ -141,7 +152,8 @@ class RealEngine:
 
             if n_hit:
                 # --- pool fetch path: scatter-read hit blocks, skip prefill ---
-                with TraceAnnotation("engine.fetch"):
+                with TraceAnnotation("engine.fetch", blocks=len(hits),
+                                     bytes=len(hits) * self.pool.layout.block_bytes):
                     cache = self._fetch([b for _, b, _ in hits])
                 logits, cache, info["tail_tokens"] = self._prefill_tail(prompt, n_hit, cache)
             else:
@@ -196,21 +208,19 @@ class RealEngine:
     def _fetch(self, block_ids: list[int]) -> dict:
         """A decode cache of ``max_len`` slots holding the pool blocks
         ``block_ids`` in order."""
-        bt = self.pool.layout.block_tokens
+        layout = self.pool.layout
+        bt = layout.block_tokens
         blocks = pool_gather(self.pool.data, jnp.asarray(block_ids))
-        k_cache, v_cache = ops.kv_scatter_read(
+        parts = ops.kv_scatter_read(
             blocks, jnp.arange(len(block_ids), dtype=jnp.int32), self.max_len // bt,
-            mode=self.kernel_mode,
+            n_parts=layout.n_parts, mode=self.kernel_mode,
         )
-        cache = self._layers_to_cache(
-            k_cache.astype(jnp.dtype(self.cfg.dtype)),
-            v_cache.astype(jnp.dtype(self.cfg.dtype)),
-        )
+        cache = self._layers_to_cache(*(p.astype(jnp.dtype(self.cfg.dtype)) for p in parts))
         # pad cache seq dim up to max_len if needed
-        pad = self.max_len - cache["pos_0"]["k"].shape[2]
+        pad = self.max_len - parts[0].shape[1]
         if pad > 0:
             cache = jax.tree.map(
-                lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))),
+                lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3)),
                 cache,
             )
         return cache
@@ -239,9 +249,10 @@ class RealEngine:
         n_blocks = len(prompt) // bt
         if not n_blocks:
             return
-        k, v = self._cache_to_layers(cache)
+        parts = self._cache_to_layers(cache)
         blocks = ops.kv_gather_write(
-            k, v, jnp.arange(n_blocks, dtype=jnp.int32), bt, mode=self.kernel_mode
+            parts[0], parts[1] if len(parts) > 1 else None,
+            jnp.arange(n_blocks, dtype=jnp.int32), bt, mode=self.kernel_mode,
         )
         with TraceAnnotation("engine.allocate"):
             block_ids = self.pool.allocate(n_blocks)

@@ -96,6 +96,19 @@ def test_tail_span_counts_tokens_and_chunks(traced):
                 and tail.start <= s.start and s.end <= tail.end]
 
 
+def test_fetch_span_counts_blocks_and_bytes(traced):
+    """``engine.fetch`` carries the blocks a hit fetches and their bytes."""
+    from repro.configs.registry import reduced_config
+    from repro.core.pool import PoolLayout
+
+    *_, path = traced
+    data = jax.profiler.ProfileData.from_file(path)
+    stats = [dict(e.stats) for p in data.planes if p.name == "/host:CPU"
+             for ln in p.lines for e in ln.events if e.name == "engine.fetch"]
+    block = PoolLayout.for_model(reduced_config("olmo-1b")).block_bytes
+    assert [(s["blocks"], s["bytes"]) for s in stats] == [(2, 2 * block)]
+
+
 def test_served_programs_have_names():
     """The served path's device programs carry the names the benchmark's
     trace reduction looks up."""

@@ -111,6 +111,33 @@ def test_kv_transfer_roundtrip(dtype, L, n_slots, bt, hkv, hd):
     assert jnp.array_equal(k2, k3) and jnp.array_equal(v2, v3)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("L,n_slots,bt,width", [(5, 8, 16, 576), (3, 4, 16, 48)])
+def test_one_part_transfer_roundtrip(dtype, L, n_slots, bt, width):
+    """A latent cache, one part of any width a layer: pool blocks of shape
+    (L, bt, width) written and read back bit for bit, the unwritten slots
+    zero, the kernels equal to the oracle."""
+    from repro.core.pool import BelugaPool, PoolLayout
+
+    layout = PoolLayout.latent(bt, L, width)
+    assert layout.n_fragments == L and layout.block_bytes == L * bt * width * 2
+    pool = BelugaPool(layout, n_blocks=8, n_shards=8, backing="jax")
+    assert pool.data.shape == (8, L, bt, width)
+    lat = _randn((L, n_slots * bt, width), dtype)
+    slots = jnp.asarray(RNG.choice(n_slots, size=3, replace=False), jnp.int32)
+    blocks = ops.kv_gather_write(lat, None, slots, bt, mode="interpret")
+    assert blocks.shape == (3, L, bt, width)
+    assert jnp.array_equal(blocks, ref.kv_gather_write_ref(lat, None, slots, bt))
+    (back,) = ops.kv_scatter_read(blocks, slots, n_slots, n_parts=1, mode="interpret")
+    written = np.zeros(n_slots * bt, bool)
+    for s in np.asarray(slots):
+        written[s * bt : (s + 1) * bt] = True
+    assert jnp.array_equal(back[:, written], lat[:, written])
+    assert not jnp.any(back[:, ~written])
+    (oracle,) = ops.kv_scatter_read(blocks, slots, n_slots, n_parts=1, mode="jnp")
+    assert jnp.array_equal(back, oracle)
+
+
 @pytest.mark.parametrize("kernel", ["kv_gather_write", "kv_scatter_read"])
 def test_pallas_mode_raises_off_tpu(kernel):
     """No silent fallback: off a TPU, compiled Pallas is refused, never

@@ -2,7 +2,8 @@
 
 The TPU compiler refuses here what it would refuse on the chip: tiling a
 kernel cannot use, more fast memory than a kernel may hold, a program that
-does not fit in HBM. Shapes are qwen3-32b's published KV and model widths.
+does not fit in HBM. Shapes are qwen3-32b's published KV and model widths,
+and deepseek-v3-ep32's at the 5 layers of ``bench/configs/deepseek-v3-ep32-5L.json``.
 
 The topology is described inside the module fixture, never at import: only
 one process may load the TPU library, and every pytest worker imports this
@@ -67,6 +68,61 @@ def test_kv_transfer_kernel_compiles_for_v5e(one_chip, kernel, n_layers):
         )
     assert "tpu_custom_call" in lowered.as_text()
     assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
+@pytest.mark.parametrize("kernel", ["kv_gather_write", "kv_scatter_read"])
+def test_one_part_transfer_kernel_compiles_for_v5e(one_chip, kernel):
+    """The latent layout's copies: one 576-wide part a layer, 5 layers."""
+    from repro.kernels import kv_transfer
+
+    L, width = 5, 576
+    ids = _sds((N_BLOCKS,), jnp.int32, one_chip)
+    if kernel == "kv_gather_write":
+        lat = _sds((L, N_SLOTS * BT, width), jnp.bfloat16, one_chip)
+        lowered = jax.jit(kv_transfer.kv_gather_write, static_argnums=3).lower(
+            lat, None, ids, BT
+        )
+    else:
+        blocks = _sds((N_BLOCKS, L, BT, width), jnp.bfloat16, one_chip)
+        lowered = jax.jit(
+            lambda b, i: kv_transfer.kv_scatter_read(b, i, N_SLOTS, n_parts=1)
+        ).lower(blocks, ids)
+    assert "tpu_custom_call" in lowered.as_text()
+    assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
+def _deepseek_5l(one_chip):
+    """deepseek-v3-ep32 at published widths and 5 layers, with an
+    8,512-slot cache: the model, its weights and cache as shapes."""
+    from repro.configs.base import RuntimeConfig
+    from repro.configs.registry import cut_depth
+    from repro.models import Model
+
+    model = Model(cut_depth(get_config("deepseek-v3-ep32"), 5),
+                  RuntimeConfig(remat="none", decode_kv="replicated"))
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda x: _sds(x.shape, x.dtype, one_chip), t
+    )
+    params = on_chip(jax.eval_shape(model.init, jax.random.key(0)))
+    return model, params, on_chip(jax.eval_shape(lambda: model.init_cache(1, 8512)))
+
+
+@pytest.mark.parametrize("program", ["decode_fn", "extend_fn", "prefill_fn"])
+def test_deepseek_programs_compile_for_v5e(one_chip, program):
+    """The served programs of the latent-attention MoE model: a decode step, a
+    256-token tail chunk, and an 8,192-token prefill, weights as arguments."""
+    from repro.serving.real_runner import TAIL_CHUNK
+
+    model, params, cache = _deepseek_5l(one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    if program == "decode_fn":
+        lowered = jax.jit(model.decode_fn).lower(params, cache, i32(1), i32(1))
+    elif program == "extend_fn":
+        lowered = jax.jit(model.extend_fn).lower(params, cache, i32(1, TAIL_CHUNK), i32(), i32())
+    else:
+        fn = lambda p, b: model.prefill_fn(p, b, max_len=8512)  # noqa: E731
+        lowered = jax.jit(fn).lower(params, {"tokens": i32(1, 8192)})
+    _assert_fits_with_weights_as_arguments(lowered.compile(), params)
 
 
 def _one_layer_qwen3(one_chip):
